@@ -87,7 +87,7 @@ def _autotune_gemms(log, rows, *, shape, menu, n, table, save):
     reports = {}
     log(f"# Autotune {M}x{K}x{N} m=8 (menu {menu}, "
         f"backend={jax.default_backend()}"
-        f"{'-interpret' if ops.INTERPRET else ''})")
+        f"{'-interpret' if ops.interpret() else ''})")
     for op, fn in runners.items():
         best, rep = autotune.autotune_op(op, fn, M, K, N, mantissa_bits=8,
                                          table=table, menu=menu, n=n,
@@ -133,7 +133,7 @@ def run(log=print, smoke: bool = False):
     M, K, N = mode["shape"]
     record = {
         "backend": jax.default_backend()
-        + ("-interpret" if ops.INTERPRET else ""),
+        + ("-interpret" if ops.interpret() else ""),
         "shape": {"M": M, "K": K, "N": N},
         "mantissa_bits": 8,
         "menu": list(mode["menu"]),

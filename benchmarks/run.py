@@ -13,6 +13,8 @@ def main() -> None:
                     help="substring filter on benchmark module name")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (design_space, kernel_bench, numerics_bench,
                             obs_bench, serve_bench, table1_narrow_fp,
                             table2_image_cls, table3_lstm_lm,

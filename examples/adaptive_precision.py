@@ -36,6 +36,7 @@ import jax
 from repro.configs import get_arch
 from repro.core import HBFPConfig
 from repro.data import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.numerics import ControllerConfig, PrecisionController, TapConfig
 from repro.obs import JSONLSink, Recorder
@@ -55,6 +56,7 @@ def main():
     ap.add_argument("--runlog", default="results/runlog.jsonl")
     ap.add_argument("--ckpt", default="results/adaptive_ckpt")
     args = ap.parse_args()
+    enable_compile_cache()
 
     arch = get_arch("yi-9b").smoke()
     # paper-fidelity tile 24: small tiles make mantissa clipping measurable

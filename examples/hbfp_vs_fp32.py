@@ -10,6 +10,7 @@ import jax
 
 from repro.configs import get_arch
 from repro.data import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.optim import make_schedule
 from repro.precision import parse_policy
@@ -53,6 +54,7 @@ def main():
     ap.add_argument("--steps", type=int, default=120)
     ap.add_argument("--arch", default="yi-9b")
     args = ap.parse_args()
+    enable_compile_cache()
 
     arch = get_arch(args.arch).smoke()
     pipe = SyntheticLM(arch.vocab_size, 33, 8, seed=11)

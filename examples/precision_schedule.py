@@ -21,6 +21,7 @@ import jax
 
 from repro.configs import get_arch
 from repro.data import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.optim import make_schedule
 from repro.precision import QuantSite, parse_policy
@@ -35,6 +36,7 @@ def main():
     ap.add_argument("--seq", type=int, default=32)
     ap.add_argument("--ckpt-dir", default="/tmp/hbfp_sched_ckpt")
     args = ap.parse_args()
+    enable_compile_cache()
 
     arch = get_arch("yi-9b").smoke()
     # 4-bit for the first ~85% of steps, widen 8 -> 16 at the end; wgrad

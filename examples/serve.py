@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import arch_ids, get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params, make_cache
 from repro.precision import parse_policy
 from repro.train.serve_step import (make_decode_fn, make_prefill_fn,
@@ -27,6 +28,7 @@ def main():
     ap.add_argument("--precision", default="8",
                     help='serving policy spec, e.g. "8", "8; lm_head:12"')
     args = ap.parse_args()
+    enable_compile_cache()
 
     arch = get_arch(args.arch).smoke()
     if arch.input_kind != "tokens" or arch.n_codebooks > 1:

@@ -18,6 +18,7 @@ import jax
 
 from repro.configs import arch_ids, get_arch
 from repro.data import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.optim import make_schedule
 from repro.precision import parse_policy
@@ -38,6 +39,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/hbfp_train_ckpt")
     ap.add_argument("--full-size", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     arch = get_arch(args.arch)
     if not args.full_size:

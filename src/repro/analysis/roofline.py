@@ -28,16 +28,6 @@ PEAK_FLOPS_INT8 = 394e12
 HBM_BW = 819e9
 ICI_BW_PER_LINK = 50e9
 
-
-def cost_analysis_dict(compiled) -> Dict:
-    """compiled.cost_analysis() as a flat dict across jax versions: older
-    releases return the dict directly, jax ≥0.4.35 returns a one-element
-    list of per-computation dicts."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
-
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
     "s64": 8, "u64": 8, "s32": 4, "u32": 4, "s16": 2, "u16": 2,

@@ -48,9 +48,7 @@ def compressed_psum_tree(grads, axis_name: str, *,
     Must be called inside shard_map with `axis_name` manual. Returns
     (mean-reduced grads, new residual pytree for error feedback).
     """
-    # jax.lax.axis_size landed after 0.4.x; psum of 1 is the portable form
-    n = jax.lax.axis_size(axis_name) if hasattr(jax.lax, "axis_size") \
-        else jax.lax.psum(1, axis_name)
+    n = jax.lax.axis_size(axis_name)
 
     def one(g, r):
         gf = g.astype(jnp.float32)

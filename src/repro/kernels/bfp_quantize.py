@@ -35,33 +35,44 @@ def _quantize_kernel(x_ref, seed_ref, *out_refs, mantissa_bits,
         mant_ref, exp_ref, clip_ref, emin_ref, emax_ref = out_refs
     else:
         mant_ref, exp_ref = out_refs
-    x = x_ref[...].astype(jnp.float32)
-    g = x.reshape(block_r // tile_r, tile_r, block_c // tile_c, tile_c)
-    amax = jnp.abs(g).max(axis=(1, 3), keepdims=True)
-
-    idx = None
-    seed = None
-    if stochastic:
-        i, j = pl.program_id(0), pl.program_id(1)
-        rows = jax.lax.broadcasted_iota(jnp.int32, (block_r, block_c), 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (block_r, block_c), 1)
-        gidx = (i * block_r + rows) * n_cols + (j * block_c + cols)
-        idx = gidx.reshape(g.shape)
-        seed = seed_ref[0, 0]
-
-    q, delta, clipped = quantize_block(g, mantissa_bits, amax,
-                                       stochastic=stochastic, seed=seed,
-                                       idx=idx, with_clip=True)
+    nr, nc = block_r // tile_r, block_c // tile_c
+    i, j = pl.program_id(0), pl.program_id(1)
+    seed = seed_ref[0, 0] if stochastic else None
     mdt = jnp.int8 if mantissa_bits <= 8 else jnp.int16
-    mant_ref[...] = q.reshape(block_r, block_c).astype(mdt)
-    dbits = jax.lax.bitcast_convert_type(delta, jnp.int32)
-    e = ((dbits >> 23) & 0xFF) - 127 + (mantissa_bits - 2)
-    et = e[:, 0, :, 0]
-    exp_ref[...] = et.astype(jnp.int8)
+    # per-tile results gather into [nr, nc] grids by select: each tile is
+    # a static, tile-aligned slice of the slab (Mosaic cannot reshape the
+    # slab into tile groups)
+    ti = jax.lax.broadcasted_iota(jnp.int32, (nr, nc), 0)
+    tj = jax.lax.broadcasted_iota(jnp.int32, (nr, nc), 1)
+    exps = jnp.zeros((nr, nc), jnp.int32)
+    clips = jnp.zeros((nr, nc), jnp.int32)
+    for a in range(nr):
+        for b in range(nc):
+            rows = pl.ds(a * tile_r, tile_r)
+            cols = pl.ds(b * tile_c, tile_c)
+            x = x_ref[rows, cols].astype(jnp.float32)
+            idx = None
+            if stochastic:
+                r = jax.lax.broadcasted_iota(jnp.int32, (tile_r, tile_c), 0)
+                c = jax.lax.broadcasted_iota(jnp.int32, (tile_r, tile_c), 1)
+                idx = ((i * block_r + a * tile_r + r) * n_cols
+                       + (j * block_c + b * tile_c + c))
+            q, delta, clipped = quantize_block(
+                x, mantissa_bits, jnp.abs(x).max(keepdims=True),
+                stochastic=stochastic, seed=seed, idx=idx, with_clip=True)
+            mant_ref[rows, cols] = q.astype(mdt)
+            dbits = jax.lax.bitcast_convert_type(delta, jnp.int32)
+            e = ((dbits >> 23) & 0xFF) - 127 + (mantissa_bits - 2)
+            here = (ti == a) & (tj == b)
+            exps = jnp.where(here, e, exps)
+            if with_stats:
+                n_clip = clipped.astype(jnp.int32).sum(keepdims=True)
+                clips = jnp.where(here, n_clip, clips)
+    exp_ref[0, 0] = exps
     if with_stats:
-        clip_ref[...] = clipped.sum(axis=(1, 3)).astype(jnp.int32)
-        emin_ref[...] = et.min(keepdims=True).astype(jnp.int32)
-        emax_ref[...] = et.max(keepdims=True).astype(jnp.int32)
+        clip_ref[0, 0] = clips
+        emin_ref[0, 0] = exps.min(keepdims=True)
+        emax_ref[0, 0] = exps.max(keepdims=True)
 
 
 def _fit_block(n_tiles: int, want_tiles: int) -> int:
@@ -101,29 +112,25 @@ def bfp_quantize_pallas(x, seed, *, mantissa_bits: int = 8,
     block_c = tc * _fit_block(Cp // tc, max(min(block_c, Cp) // tc, 1))
     mdt = jnp.int8 if mantissa_bits <= 8 else jnp.int16
     grid = (Rp // block_r, Cp // block_c)
+    nr, nc = block_r // tr, block_c // tc
     kernel = functools.partial(
         _quantize_kernel, mantissa_bits=mantissa_bits, tile_r=tr, tile_c=tc,
         stochastic=stochastic, block_r=block_r, block_c=block_c, n_cols=Cp,
         with_stats=with_stats)
-    out_specs = [
-        pl.BlockSpec((block_r, block_c), lambda i, j: (i, j)),
-        pl.BlockSpec((block_r // tr, block_c // tc), lambda i, j: (i, j)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((Rp, Cp), mdt),
-        jax.ShapeDtypeStruct((Rp // tr, Cp // tc), jnp.int8),
-    ]
+    # per-slab grids are [gr, gc, nr, nc] (their block's last two dims are
+    # the whole array's, which Mosaic's tiling rule accepts) and are laid
+    # out as the [Rp/tr, Cp/tc] tile grid after the call
+    per_tile = pl.BlockSpec((1, 1, nr, nc), lambda i, j: (i, j, 0, 0))
+    per_slab = pl.BlockSpec((1, 1, 1, 1), lambda i, j: (i, j, 0, 0))
+    out_specs = [pl.BlockSpec((block_r, block_c), lambda i, j: (i, j)),
+                 per_tile]
+    out_shape = [jax.ShapeDtypeStruct((Rp, Cp), mdt),
+                 jax.ShapeDtypeStruct(grid + (nr, nc), jnp.int32)]
     if with_stats:
-        out_specs += [
-            pl.BlockSpec((block_r // tr, block_c // tc), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-        ]
-        out_shape += [
-            jax.ShapeDtypeStruct((Rp // tr, Cp // tc), jnp.int32),
-            jax.ShapeDtypeStruct(grid, jnp.int32),
-            jax.ShapeDtypeStruct(grid, jnp.int32),
-        ]
+        out_specs += [per_tile, per_slab, per_slab]
+        out_shape += [jax.ShapeDtypeStruct(grid + (nr, nc), jnp.int32),
+                      jax.ShapeDtypeStruct(grid + (1, 1), jnp.int32),
+                      jax.ShapeDtypeStruct(grid + (1, 1), jnp.int32)]
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -134,6 +141,15 @@ def bfp_quantize_pallas(x, seed, *, mantissa_bits: int = 8,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="bfp_quantize",
     )(x, seed)
+
+    def tile_grid(t):
+        return t.transpose(0, 2, 1, 3).reshape(Rp // tr, Cp // tc)
+
     mant = out[0][:R, :C]
-    return (mant, *out[1:])
+    exps = tile_grid(out[1]).astype(jnp.int8)
+    if not with_stats:
+        return mant, exps
+    return (mant, exps, tile_grid(out[2]), out[3].reshape(grid),
+            out[4].reshape(grid))

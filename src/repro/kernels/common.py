@@ -93,6 +93,21 @@ def pow2(e):
     return jax.lax.bitcast_convert_type(bits, jnp.float32)
 
 
+def _group_max(a, block: int, axis: int):
+    """Max of the non-negative 2-D `a` over `block`-sized groups along
+    `axis`, broadcast back to a's shape. Built from masked full-tile
+    reductions rather than a reshape into groups: Mosaic cannot split a
+    vreg's lane (or sublane) axis into sub-128 groups, and max is exact,
+    so the result is bit-identical to the reshape form."""
+    grp = jax.lax.broadcasted_iota(jnp.int32, a.shape, axis) // block
+    out = jnp.zeros_like(a)
+    for g in range(a.shape[axis] // block):
+        m = grp == g
+        gmax = jnp.where(m, a, 0.0).max(axis=axis, keepdims=True)
+        out = jnp.where(m, gmax, out)
+    return out
+
+
 def row_group_amax(x, block: int):
     """Per-row |x| max over `block`-sized groups of the last axis — the
     activation/gradient exponent granularity inside one kernel tile
@@ -102,30 +117,28 @@ def row_group_amax(x, block: int):
     the sim backend bit-for-bit on aligned shapes. Returns an array
     broadcastable against x."""
     a = jnp.abs(x)
-    r, c = x.shape
+    c = x.shape[1]
     if not block or block >= c:
         return a.max(axis=1, keepdims=True)
     if c % block:
         raise ValueError(f"block {block} must divide the tile K edge {c}")
-    g = a.reshape(r, c // block, block).max(axis=2, keepdims=True)
-    return jnp.broadcast_to(g, (r, c // block, block)).reshape(r, c)
+    return _group_max(a, block, 1)
 
 
 def tile_group_amax(w, block: int):
     """|w| max over (block, block) sub-tiles of one 2-D kernel tile — the
     weight exponent granularity (DESIGN.md §13). block=0 ⇒ one amax for
-    the whole tile (today's semantics); block clamps per-dim to the tile
-    edges like `bfp._tile_view`. Returns an array broadcastable against
-    w."""
+    the whole tile (today's semantics), kept 2-D ([1, 1]) because Mosaic
+    cannot bitcast a 0-d scalar; block clamps per-dim to the tile edges
+    like `bfp._tile_view`. Returns an array broadcastable against w."""
     a = jnp.abs(w)
     if not block:
-        return a.max()
+        return a.max(keepdims=True)
     r, c = w.shape
     rb, cb = min(block, r), min(block, c)
     if r % rb or c % cb:
         raise ValueError(f"block {block} must divide tile edges {(r, c)}")
-    g = a.reshape(r // rb, rb, c // cb, cb).max(axis=(1, 3), keepdims=True)
-    return jnp.broadcast_to(g, (r // rb, rb, c // cb, cb)).reshape(r, c)
+    return _group_max(_group_max(a, cb, 1), rb, 0)
 
 
 def quantize_block(x, mantissa_bits: int, amax, *, stochastic: bool,

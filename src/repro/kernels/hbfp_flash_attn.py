@@ -118,8 +118,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
         o_ref[0] = (acc_ref[...] /
                     jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
         if with_lse:
-            lse_ref[0, :] = (m_ref[...] +
-                             jnp.log(jnp.maximum(l_ref[...], 1e-30)))[:, 0]
+            lse_ref[0] = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-30))
 
 
 @functools.partial(jax.jit, static_argnames=("m_bits", "m_qk", "m_pv",
@@ -146,9 +145,13 @@ def hbfp_flash_attention(q, k, v, *, m_bits: int = 8, m_qk: int = 0,
     out_shape = jax.ShapeDtypeStruct((BH, S, hd), q.dtype)
     out_spec = pl.BlockSpec((1, bq, hd), lambda b, i, j: (b, i, 0))
     if with_lse:
-        out_shape = [out_shape, jax.ShapeDtypeStruct((BH, S), jnp.float32)]
-        out_spec = [out_spec, pl.BlockSpec((1, bq), lambda b, i, j: (b, i))]
-    return pl.pallas_call(
+        # per-row vectors travel as [BH, S, 1] columns: a (1, bq, 1) block
+        # meets Mosaic's (8, 128) tiling rule, a (1, bq) row block does not
+        out_shape = [out_shape,
+                     jax.ShapeDtypeStruct((BH, S, 1), jnp.float32)]
+        out_spec = [out_spec,
+                    pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))]
+    out = pl.pallas_call(
         kernel,
         grid=(BH, S // bq, n_k),
         in_specs=[
@@ -162,7 +165,11 @@ def hbfp_flash_attention(q, k, v, *, m_bits: int = 8, m_qk: int = 0,
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, hd), jnp.float32)],
         interpret=interpret,
+        name="hbfp_flash_fwd",
     )(q, k, v)
+    if with_lse:
+        return out[0], out[1][..., 0]
+    return out
 
 
 # ----------------------------------------------------------------------------
@@ -181,7 +188,7 @@ def _recompute_p(q, k, lse, qb, kb, m_qk, bq, bk, scale, causal):
         qpos = qb * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         kpos = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         s = jnp.where(kpos <= qpos, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])                       # [bq, bk]
+    p = jnp.exp(s - lse)                                # [bq, bk]
     return p, (qq, dq), (kq, dk)
 
 
@@ -199,7 +206,7 @@ def _dsoft(p, do_q, do_d, v, delta, m_pv):
     PV-side operands at the PV width), then ds = p ∘ (dp − D)."""
     vq, dv = _bfp_rows(v, m_pv)
     dp = _qdot(do_q, vq.T, m_pv) * (do_d * dv.T)        # [bq, bk]
-    return p * (dp - delta[:, None])
+    return p * (dp - delta)
 
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -297,14 +304,17 @@ def hbfp_flash_attention_bwd(q, k, v, o, lse, do, *, m_bits: int = 8,
     bq, bk = min(bq, S), min(bk, S)
     assert S % bq == 0 and S % bk == 0, (S, bq, bk)
     scale = 1.0 / (hd ** 0.5)
-    delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)
+    # lse/delta as [BH, S, 1] columns (see hbfp_flash_attention)
+    delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(
+        -1, keepdims=True)
+    lse = lse[..., None]
     specs = [
         pl.BlockSpec((1, bq, hd), lambda b, i, j: (b, i, 0)),   # q
         pl.BlockSpec((1, bk, hd), lambda b, i, j: (b, j, 0)),   # k
         pl.BlockSpec((1, bk, hd), lambda b, i, j: (b, j, 0)),   # v
         pl.BlockSpec((1, bq, hd), lambda b, i, j: (b, i, 0)),   # do
-        pl.BlockSpec((1, bq), lambda b, i, j: (b, i)),          # lse
-        pl.BlockSpec((1, bq), lambda b, i, j: (b, i)),          # delta
+        pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),    # lse
+        pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),    # delta
     ]
     dq = pl.pallas_call(
         functools.partial(_flash_dq_kernel, m_qk=m_qk or m_bits,
@@ -316,6 +326,7 @@ def hbfp_flash_attention_bwd(q, k, v, o, lse, do, *, m_bits: int = 8,
         out_shape=jax.ShapeDtypeStruct((BH, S, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
         interpret=interpret,
+        name="hbfp_flash_dq",
     )(q, k, v, do, lse, delta)
     # dk/dv grid swaps the roles: (b, k-block, q-block), q innermost
     specs_kv = [
@@ -323,8 +334,8 @@ def hbfp_flash_attention_bwd(q, k, v, o, lse, do, *, m_bits: int = 8,
         pl.BlockSpec((1, bk, hd), lambda b, j, i: (b, j, 0)),   # k
         pl.BlockSpec((1, bk, hd), lambda b, j, i: (b, j, 0)),   # v
         pl.BlockSpec((1, bq, hd), lambda b, j, i: (b, i, 0)),   # do
-        pl.BlockSpec((1, bq), lambda b, j, i: (b, i)),          # lse
-        pl.BlockSpec((1, bq), lambda b, j, i: (b, i)),          # delta
+        pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),    # lse
+        pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),    # delta
     ]
     dk, dv = pl.pallas_call(
         functools.partial(_flash_dkv_kernel, m_qk=m_qk or m_bits,
@@ -339,6 +350,7 @@ def hbfp_flash_attention_bwd(q, k, v, o, lse, do, *, m_bits: int = 8,
         scratch_shapes=[pltpu.VMEM((bk, hd), jnp.float32),
                         pltpu.VMEM((bk, hd), jnp.float32)],
         interpret=interpret,
+        name="hbfp_flash_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

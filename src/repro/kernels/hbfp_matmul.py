@@ -175,6 +175,7 @@ def hbfp_matmul_pallas(x, w, seed=None, *, mantissa_bits: int = 8,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="hbfp_matmul_fwd",
     )(x, w, seed)
 
 
@@ -292,6 +293,7 @@ def hbfp_dgrad_pallas(g, w, seed=None, *, mantissa_bits: int = 8,
         out_shape=jax.ShapeDtypeStruct((M, K), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32)],
         interpret=interpret,
+        name="hbfp_matmul_dgrad",
     )(g, w, seed)
 
 
@@ -380,4 +382,5 @@ def hbfp_wgrad_pallas(x, g, seed=None, *, mantissa_bits: int = 8,
         out_shape=jax.ShapeDtypeStruct((K, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)],
         interpret=interpret,
+        name="hbfp_matmul_wgrad",
     )(x, g, seed)

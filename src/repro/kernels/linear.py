@@ -73,7 +73,7 @@ def _fwd_impl(spec: KernelSpec, x2, w, seed):
         _pad2(x2, bm, bk), _pad2(w, bk, bn), seed,
         mantissa_bits=spec.mantissa_bits, stochastic=spec.stochastic,
         quantize_w=spec.quantize_w, block=spec.block, bm=bm, bk=bk, bn=bn,
-        interpret=ops.INTERPRET)
+        interpret=ops.interpret())
     return y[:M, :N].astype(x2.dtype)
 
 
@@ -113,7 +113,7 @@ def _vjp_bwd(spec, res, g):
                    spec.block, spec.block),
         mantissa_bits=m_d, stochastic=spec.stochastic,
         quantize_w=spec.quantize_w, block=spec.block, bm=bm, bk=bk, bn=bn,
-        interpret=ops.INTERPRET)[:M, :K]
+        interpret=ops.interpret())[:M, :K]
     # wgrad: dw[K,N] = Q(x)^T·Q(g), contraction over the token axis M
     bm, bk, bn = autotune.align_tiles(
         autotune.clip_tiles(spec.wgrad, M, K, N), spec.block)
@@ -122,7 +122,7 @@ def _vjp_bwd(spec, res, g):
         _role_seed(seed, "wgrad", m_w, spec.mantissa_bits,
                    spec.block, spec.block),
         mantissa_bits=m_w, stochastic=spec.stochastic, block=spec.block,
-        bm=bm, bk=bk, bn=bn, interpret=ops.INTERPRET)[:K, :N]
+        bm=bm, bk=bk, bn=bn, interpret=ops.interpret())[:K, :N]
     return dx.astype(x2.dtype), dw.astype(w.dtype), _zero_cotangent(seed)
 
 

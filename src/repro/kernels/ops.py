@@ -2,8 +2,10 @@
 multiples, batching, autotuned tile resolution, and CPU (interpret) / TPU
 dispatch.
 
-On this container (CPU) the kernels always run with interpret=True; on TPU
-the same call sites compile to Mosaic. `INTERPRET` flips automatically.
+Off a TPU the kernels run through the Pallas interpreter; on a TPU the
+same call sites compile to Mosaic. `interpret()` decides at trace time, so
+importing this module touches no JAX backend, and on a TPU a kernel that
+Mosaic refuses raises instead of falling back.
 
 Tile sizes: pass bm/bk/bn explicitly to pin them, or leave None and the
 wrapper resolves them at trace time from the autotuner table
@@ -22,7 +24,11 @@ from repro.kernels.bfp_quantize import bfp_quantize_pallas
 from repro.kernels.hbfp_matmul import (hbfp_dgrad_pallas, hbfp_matmul_pallas,
                                        hbfp_wgrad_pallas)
 
-INTERPRET = jax.default_backend() != "tpu"
+
+def interpret() -> bool:
+    """True unless the default JAX backend is a TPU. Read at trace time by
+    every kernel call site (never at import)."""
+    return jax.default_backend() != "tpu"
 
 
 def _pad_to(x, mults):
@@ -58,7 +64,7 @@ def bfp_quantize(x, seed=0, *, mantissa_bits=8, tile=128, stochastic=False,
     out = bfp_quantize_pallas(x, seed, mantissa_bits=mantissa_bits,
                               tile_r=tile, tile_c=tile,
                               stochastic=stochastic, with_stats=with_stats,
-                              interpret=INTERPRET)
+                              interpret=interpret())
     if not with_stats:
         return out
     m, e, clip_count, emin, emax = out
@@ -91,7 +97,7 @@ def hbfp_matmul(x, w, seed=None, *, mantissa_bits=8, stochastic=False,
     y = hbfp_matmul_pallas(xp, wp, seed_arr, mantissa_bits=mantissa_bits,
                            stochastic=stochastic, quantize_w=quantize_w,
                            block=block, bm=bm, bk=bk, bn=bn,
-                           interpret=INTERPRET)
+                           interpret=interpret())
     y = y[:x2.shape[0], :N0]
     return y.reshape(*lead, M0, N0)
 
@@ -109,7 +115,7 @@ def hbfp_dgrad(g, w, seed=None, *, mantissa_bits=8, stochastic=False,
     dx = hbfp_dgrad_pallas(gp, wp, seed_arr, mantissa_bits=mantissa_bits,
                            stochastic=stochastic, quantize_w=quantize_w,
                            block=block, bm=bm, bk=bk, bn=bn,
-                           interpret=INTERPRET)
+                           interpret=interpret())
     return dx[:M0, :K0]
 
 
@@ -125,5 +131,5 @@ def hbfp_wgrad(x, g, seed=None, *, mantissa_bits=8, stochastic=False,
     seed_arr = None if seed is None else jnp.full((1, 1), seed, jnp.int32)
     dw = hbfp_wgrad_pallas(xp, gp, seed_arr, mantissa_bits=mantissa_bits,
                            stochastic=stochastic, block=block,
-                           bm=bm, bk=bk, bn=bn, interpret=INTERPRET)
+                           bm=bm, bk=bk, bn=bn, interpret=interpret())
     return dw[:K0, :N0]

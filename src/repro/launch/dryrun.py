@@ -44,7 +44,7 @@ from repro.sharding.partitioning import (batch_specs, cache_specs,
                                          opt_state_specs)
 from repro.train import init_train_state, make_train_step
 from repro.analysis.roofline import (collective_bytes_from_text,
-                                     cost_analysis_dict, roofline_terms)
+                                     roofline_terms)
 
 SHAPES = {
     "train_4k":    dict(kind="train",   seq=4096,   batch=256),
@@ -295,7 +295,7 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool,
                                      ssm_unroll=True, ssm_chunk=ssm_chunk)
             fn, args = build_cell(a2, shape_name, mesh, hbfp, opts)
             compiled = fn.lower(*args).compile()
-            ca = cost_analysis_dict(compiled)
+            ca = compiled.cost_analysis()
             coll = collective_bytes_from_text(compiled.as_text())
             costs[L] = {"flops": float(ca.get("flops", 0.0)),
                         "bytes": float(ca.get("bytes accessed", 0.0)),

@@ -142,7 +142,7 @@ def flash_mha(q, k, v, ctx):
         w = rw.apply(ctx.cfg).mantissa_bits if rw is not None else m
         widths[role] = 0 if w == m else w
     spec = FlashSpec(m_bits=m, bq=blk, bk=blk,
-                     causal=True, interpret=kops.INTERPRET,
+                     causal=True, interpret=kops.interpret(),
                      m_qk=widths["attn_qk"], m_pv=widths["attn_pv"])
     out = flash_attention_vjp(spec, q.reshape(B * H, S, hd),
                               k.reshape(B * H, S, hd),

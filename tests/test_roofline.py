@@ -45,9 +45,8 @@ def test_cost_analysis_is_per_device():
     f = lambda x, w: (x @ w).sum()
     x = jax.ShapeDtypeStruct((128, 64), jnp.float32)
     w = jax.ShapeDtypeStruct((64, 32), jnp.float32)
-    from repro.analysis.roofline import cost_analysis_dict
     c = jax.jit(f).lower(x, w).compile()
-    assert abs(cost_analysis_dict(c)["flops"] - 2 * 128 * 64 * 32) \
+    assert abs(c.cost_analysis()["flops"] - 2 * 128 * 64 * 32) \
         < 0.1 * 2 * 128 * 64 * 32
 
 

@@ -122,11 +122,7 @@ from jax.sharding import PartitionSpec as P
 from functools import partial
 from repro.core.grad_compress import compressed_psum_tree
 mesh = jax.make_mesh((8,), ('data',))
-if hasattr(jax, 'shard_map'):           # jax >= 0.5
-    smap = partial(jax.shard_map, check_vma=False)
-else:                                   # jax 0.4.x
-    from jax.experimental.shard_map import shard_map
-    smap = partial(shard_map, check_rep=False)
+smap = partial(jax.shard_map, check_vma=False)
 g = {'w': jax.random.normal(jax.random.key(0), (8, 64, 128))}
 @partial(smap, mesh=mesh, in_specs=P('data'), out_specs=P(None))
 def red(gs):
