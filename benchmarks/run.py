@@ -16,9 +16,8 @@ def main() -> None:
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
     from benchmarks import (design_space, kernel_bench, numerics_bench,
-                            obs_bench, serve_bench, table1_narrow_fp,
-                            table2_image_cls, table3_lstm_lm,
-                            throughput_model)
+                            serve_bench, table1_narrow_fp, table2_image_cls,
+                            table3_lstm_lm, throughput_model)
     suites = [
         ("table1_narrow_fp", table1_narrow_fp),
         ("table2_image_cls", table2_image_cls),
@@ -27,7 +26,6 @@ def main() -> None:
         ("throughput_model", throughput_model),
         ("kernel_bench", kernel_bench),
         ("numerics_overhead", numerics_bench),
-        ("obs_overhead", obs_bench),
         ("serve_traffic", serve_bench),
     ]
     csv = ["name,value,derived"]

@@ -116,7 +116,17 @@ def _attn_ffn_block(x, lp, ctx, arch: ArchConfig, positions, window,
                     cache, want_cache: bool, std_pos: bool = False):
     """Standard pre-norm block; gemma2 adds post-norms; hymba adds the
     parallel mamba branch. Returns (x, new_cache, aux)."""
-    aux = jnp.zeros((), jnp.float32)
+    with jax.named_scope("model.attn"):
+        x, new_cache = _attn_half(x, lp, ctx, arch, positions, window,
+                                  cache, want_cache, std_pos)
+    with jax.named_scope("model.ffn"):
+        x, aux = _ffn_half(x, lp, ctx, arch)
+    return x, new_cache, aux
+
+
+def _attn_half(x, lp, ctx, arch: ArchConfig, positions, window, cache,
+               want_cache: bool, std_pos: bool):
+    """Norm, attention (and hymba's SSM branch), residual: (x, new_cache)."""
     h = rms_norm(x, lp["ln1_norm_scale"], arch.norm_eps,
                  arch.zero_centered_norm)
     a, new_kv = attention_layer(
@@ -148,8 +158,12 @@ def _attn_ffn_block(x, lp, ctx, arch: ArchConfig, positions, window,
     if arch.post_norms:
         a = rms_norm(a, lp["post1_norm_scale"], arch.norm_eps,
                      arch.zero_centered_norm)
-    x = x + arch.residual_scale * a
+    return x + arch.residual_scale * a, new_cache
 
+
+def _ffn_half(x, lp, ctx, arch: ArchConfig):
+    """Norm, FFN or MoE, residual: (x, MoE aux loss)."""
+    aux = jnp.zeros((), jnp.float32)
     h = rms_norm(x, lp["ln2_norm_scale"], arch.norm_eps,
                  arch.zero_centered_norm)
     if arch.n_experts:
@@ -165,8 +179,7 @@ def _attn_ffn_block(x, lp, ctx, arch: ArchConfig, positions, window,
     if arch.post_norms:
         f = rms_norm(f, lp["post2_norm_scale"], arch.norm_eps,
                      arch.zero_centered_norm)
-    x = x + arch.residual_scale * f
-    return x, new_cache, aux
+    return x + arch.residual_scale * f, aux
 
 
 def _xlstm_block(x, lp, ctx, arch: ArchConfig, is_slstm, cache,
@@ -203,6 +216,11 @@ def _xlstm_block(x, lp, ctx, arch: ArchConfig, is_slstm, cache,
 # ----------------------------------------------------------------------------
 
 def _embed_in(params, batch, arch: ArchConfig, ctx):
+    with jax.named_scope("model.embed"):
+        return _embed(params, batch, arch)
+
+
+def _embed(params, batch, arch: ArchConfig):
     if arch.input_kind == "embeddings":
         x = batch["embeds"].astype(jnp.dtype(arch.dtype))
     else:
@@ -285,9 +303,10 @@ def _head_logits(params, x, arch: ArchConfig, ctx):
 
 
 def _logits(params, x, arch: ArchConfig, ctx):
-    x = rms_norm(x, params["final_norm_scale"], arch.norm_eps,
-                 arch.zero_centered_norm)
-    return _head_logits(params, x, arch, ctx)
+    with jax.named_scope("model.head"):
+        x = rms_norm(x, params["final_norm_scale"], arch.norm_eps,
+                     arch.zero_centered_norm)
+        return _head_logits(params, x, arch, ctx)
 
 
 # ----------------------------------------------------------------------------
@@ -347,9 +366,24 @@ def loss_fn(params, batch, arch: ArchConfig, ctx: Ctx,
                            std_pos=_std_positions(batch))
     if act_stats is not None:
         act_stats["final_hidden"] = tap(x)
+    labels = batch["labels"]
+    with jax.named_scope("model.head"):
+        tot = _head_nll(params, x, labels, arch, ctx)
+    T = x.shape[0] * x.shape[1]
+    denom = T * (labels.shape[2] if labels.ndim == 3 else 1)
+    nll = tot / denom
+    loss = nll + aux_weight * aux
+    metrics = {"nll": nll, "aux": aux, "loss": loss}
+    if act_stats is not None:
+        metrics["act_stats"] = act_stats
+    return loss, metrics
+
+
+def _head_nll(params, x, labels, arch: ArchConfig, ctx):
+    """Final norm, LM head and summed next-token CE over [B, S, D] hidden
+    states, in token chunks of arch.loss_chunk where they divide."""
     x = rms_norm(x, params["final_norm_scale"], arch.norm_eps,
                  arch.zero_centered_norm)
-    labels = batch["labels"]
     B, S, D = x.shape
     xt = x.reshape(B * S, D)
     lt = labels.reshape(B * S, *labels.shape[2:])
@@ -368,15 +402,8 @@ def loss_fn(params, batch, arch: ArchConfig, ctx: Ctx,
         lc = lt.reshape(nc, loss_chunk, *lt.shape[1:])
         body = jax.checkpoint(lambda c, xs: (c + ce(*xs), None))
         tot, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (xc, lc))
-    else:
-        tot = ce(xt, lt)
-    denom = T * (labels.shape[2] if labels.ndim == 3 else 1)
-    nll = tot / denom
-    loss = nll + aux_weight * aux
-    metrics = {"nll": nll, "aux": aux, "loss": loss}
-    if act_stats is not None:
-        metrics["act_stats"] = act_stats
-    return loss, metrics
+        return tot
+    return ce(xt, lt)
 
 
 def make_cache(params, arch: ArchConfig, batch_size: int, ctx_len: int):

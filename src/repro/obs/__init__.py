@@ -10,8 +10,9 @@ numerics, kernels, checkpoint, serve, and analysis:
                 textfile exposition, in-memory sink for tests;
   * `metrics` — counters / gauges / histograms with label support;
   * `trace`   — nestable span context manager that times jitted work
-                correctly via an injected `block_until_ready`, plus the
-                shared benchmark timer `time_fn`.
+                correctly via an injected `block_until_ready` and puts
+                itself on the profiler's clock via an injected
+                `annotate`, plus the shared benchmark timer `time_fn`.
 
 Every instrumented component takes an optional `recorder=` and defaults
 to the shared no-op `NULL_RECORDER`: with all sinks disabled the

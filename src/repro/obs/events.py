@@ -19,8 +19,9 @@ Two properties make this layer safe to thread through the training stack:
     way — all emission is host-side, outside jit).
 
 This module is dependency-free (stdlib only): anything that needs to sync
-device work injects a `sync` callable (e.g. `jax.block_until_ready`), see
-`obs.trace`.
+device work injects a `sync` callable (e.g. `jax.block_until_ready`), and
+anything that puts spans on a profiler's clock injects an `annotate`
+callable (e.g. `jax.profiler.TraceAnnotation`), see `obs.trace`.
 """
 from __future__ import annotations
 
@@ -127,17 +128,24 @@ class Recorder:
 
     `sync` is the optional device-synchronization callable spans use to
     time jitted work correctly (pass `jax.block_until_ready`; obs itself
-    never imports jax). Thread-safe fan-out: sinks guard their own writes;
-    the span stack is thread-local so a background checkpoint thread's
-    spans don't corrupt the training loop's nesting.
+    never imports jax). `annotate` is the optional callable that maps a
+    name to a context manager opened around every span as
+    `annotate("repro." + name)` (pass `jax.profiler.TraceAnnotation` to put
+    spans on the profiler's clock); it applies with or without sinks, so a
+    sink-less recorder with `annotate` emits nothing but still annotates.
+    Thread-safe fan-out: sinks guard their own writes; the span stack is
+    thread-local so a background checkpoint thread's spans don't corrupt
+    the training loop's nesting.
     """
 
     def __init__(self, sinks: Iterable = (), *, clock: Optional[Clock] = None,
                  sync: Optional[Callable[[Any], Any]] = None,
+                 annotate: Optional[Callable[[str], Any]] = None,
                  run_id: Optional[str] = None):
         self.sinks = list(sinks)
         self.clock = clock or SystemClock()
         self.sync_fn = sync
+        self.annotate_fn = annotate
         self.run_id = run_id
         self._local = threading.local()
 

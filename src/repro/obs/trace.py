@@ -6,6 +6,11 @@ it reads the recorder's injected clock at entry/exit and emits one
 nesting depth (the stack is per-recorder and thread-local, so a
 background checkpoint thread nests independently of the training loop).
 
+**On the profiler's clock.** When the recorder was given an `annotate`
+callable (`jax.profiler.TraceAnnotation`), each span also opens
+`annotate("repro." + name)` for its extent, with or without sinks, so a
+profiler trace shows the program's spans beside the device's ops.
+
 **Timing jitted work.** JAX dispatch is asynchronous: wall-clocking a
 jitted call measures enqueue time, not device time. A span that wraps
 jitted work must force completion before it closes — call
@@ -41,8 +46,13 @@ class Span:
         self.data = dict(data or {})
         self.synced = False
         self._t0 = None
+        self._annotation = None
 
     def __enter__(self) -> "Span":
+        annotate = self.recorder.annotate_fn
+        if annotate is not None:
+            self._annotation = annotate("repro." + self.name)
+            self._annotation.__enter__()
         self._t0 = self.recorder.clock.perf()
         self.recorder._stack().append(self)
         return self
@@ -59,6 +69,9 @@ class Span:
 
     def __exit__(self, exc_type, exc, tb) -> None:
         dur = self.recorder.clock.perf() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         stack = self.recorder._stack()
         if stack and stack[-1] is self:
             stack.pop()

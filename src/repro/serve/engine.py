@@ -39,7 +39,9 @@ weights at inference); with arch.bfp_kv_cache the pages store 8-bit BFP
 K/V. Observability as before (DESIGN.md §12) plus: "serve/prefill" /
 "serve/insert" spans, "serve/preempt" events, page-pool gauges, and a
 bounded `request_stats` (stats_cap most-recent completions are kept;
-`serve_stats_dropped_total` counts evictions).
+`serve_stats_dropped_total` counts evictions). Without a `recorder`, the
+default emits nothing but annotates every span on the profiler's clock
+(`jax.profiler.TraceAnnotation("repro.serve/step")`, ...).
 """
 from __future__ import annotations
 
@@ -54,7 +56,7 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.models import decode_step, lane_capacity, make_cache, \
     make_paged_cache, prefill
-from repro.obs import NULL_RECORDER, MetricsRegistry
+from repro.obs import MetricsRegistry, Recorder
 from repro.serve.paged_cache import (PagePool, clear_pages, insert_prefix,
                                      pages_needed, set_page_table)
 from repro.serve.sampling import GREEDY, SamplingParams, sample_tokens
@@ -97,7 +99,8 @@ class ServeEngine:
                  sampling: Optional[SamplingParams] = None,
                  stats_cap: int = 4096):
         self.arch = arch
-        self.recorder = recorder if recorder is not None else NULL_RECORDER
+        self.recorder = recorder if recorder is not None else Recorder(
+            annotate=jax.profiler.TraceAnnotation)
         if self.recorder.enabled and self.recorder.sync_fn is None:
             self.recorder.sync_fn = jax.block_until_ready
         self.metrics = metrics if metrics is not None else MetricsRegistry()

@@ -87,6 +87,13 @@ def make_train_step(arch: ArchConfig, hbfp, schedule, *, grad_accum: int = 1,
     gradient/activation fidelity (DESIGN.md §9). The main-path computation
     is bit-identical to taps=None (the weight tap reuses the same
     quantization); cadence dispatch lives in `make_step`.
+
+    Each phase runs under a `jax.named_scope`, so the compiled ops' names
+    (`op_name` metadata, and a profiler trace of the device) say which
+    phase they belong to: "hbfp.narrow" (narrowing, cast, fwd
+    constraint), "model" (the loss; its gradient is
+    "transpose(jvp(model))"), "optim.adamw" (clip and AdamW) and
+    "hbfp.widen" (the wide-BFP update). Scopes change metadata only.
     """
     compute_dtype = jnp.dtype(arch.dtype)
     seg = as_segment(hbfp, backend=arch.kernel_backend)
@@ -147,25 +154,28 @@ def make_train_step(arch: ArchConfig, hbfp, schedule, *, grad_accum: int = 1,
         and act_cfg is not None
 
     def loss_at(narrow, batch, key):
-        ctx = Ctx(key=key, compute_dtype=compute_dtype,
-                  act_constraint=act_constraint, shard_fn=shard_fn,
-                  act_tap=act_tap, policy=exec_seg)
-        return loss_fn(narrow, batch, arch, ctx)
+        # the backward pass carries transpose(jvp(model)) in its op names
+        with jax.named_scope("model"):
+            ctx = Ctx(key=key, compute_dtype=compute_dtype,
+                      act_constraint=act_constraint, shard_fn=shard_fn,
+                      act_tap=act_tap, policy=exec_seg)
+            return loss_fn(narrow, batch, arch, ctx)
 
     def train_step(state: TrainState, batch, key):
         numerics = {}
-        nkey = None
-        if stochastic:
-            nkey = jax.random.fold_in(key, 0x5EED)
-        if taps is not None and taps.weights:
-            from repro.numerics.collect import narrow_params_with_stats
-            narrow, numerics["weights"] = narrow_params_with_stats(
-                state.params, param_cfg, nkey)
-        else:
-            narrow = narrow_params(state.params, param_cfg, nkey)
-        narrow = cast(narrow)
-        if fwd_constraint is not None:
-            narrow = fwd_constraint(narrow)
+        with jax.named_scope("hbfp.narrow"):
+            nkey = None
+            if stochastic:
+                nkey = jax.random.fold_in(key, 0x5EED)
+            if taps is not None and taps.weights:
+                from repro.numerics.collect import narrow_params_with_stats
+                narrow, numerics["weights"] = narrow_params_with_stats(
+                    state.params, param_cfg, nkey)
+            else:
+                narrow = narrow_params(state.params, param_cfg, nkey)
+            narrow = cast(narrow)
+            if fwd_constraint is not None:
+                narrow = fwd_constraint(narrow)
 
         if grad_accum > 1:
             # batch leaves are [A, ...]; scan accumulates mean grads
@@ -200,12 +210,16 @@ def make_train_step(arch: ArchConfig, hbfp, schedule, *, grad_accum: int = 1,
             from repro.numerics.collect import grad_stats
             numerics["grads"] = grad_stats(grads, param_cfg)
 
-        if grad_constraint is not None:
-            grads = grad_constraint(grads)
-        updates, opt = adamw_update(grads, state.opt, state.params,
-                                    lr=schedule, weight_decay=weight_decay,
-                                    grad_clip=grad_clip)
-        params = hbfp_apply_updates(state.params, updates, param_cfg, key)
+        with jax.named_scope("optim.adamw"):
+            if grad_constraint is not None:
+                grads = grad_constraint(grads)
+            updates, opt = adamw_update(grads, state.opt, state.params,
+                                        lr=schedule,
+                                        weight_decay=weight_decay,
+                                        grad_clip=grad_clip)
+        with jax.named_scope("hbfp.widen"):
+            params = hbfp_apply_updates(state.params, updates, param_cfg,
+                                        key)
         metrics = dict(metrics)
         metrics["lr"] = schedule(opt.step) if callable(schedule) \
             else jnp.asarray(schedule)

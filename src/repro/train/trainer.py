@@ -25,12 +25,17 @@ Behaviours (exercised by tests/test_trainer.py):
     on resume, so a restarted run replays identical decisions;
   * observability (DESIGN.md §12): pass `recorder=` (an `obs.Recorder`)
     — every step runs inside a `"train/step"` span (synced via
-    block_until_ready on log-cadence steps, dispatch-only otherwise),
-    progress lines become `"train/progress"` events (and the printed
-    line is rendered from the same record), and checkpoint save/load
-    events flow through to `repro.checkpoint`. All loop timing reads the
-    recorder's *injected* clock, never `time.time()` directly, so tests
-    drive a `ManualClock` and timing output is deterministic.
+    block_until_ready on log-cadence steps, dispatch-only otherwise)
+    with two children, `"train/data"` (the batch and the step's key) and
+    `"train/dispatch"` (the `train_step` call), progress lines become
+    `"train/progress"` events (and the printed line is rendered from the
+    same record), and checkpoint save/load events flow through to
+    `repro.checkpoint`. Without one, the default is a sink-less recorder
+    that emits nothing but annotates every span on the profiler's clock
+    (`jax.profiler.TraceAnnotation("repro.train/step")`, ...), so a
+    profiler trace of the loop names the host's work. All loop timing
+    reads the recorder's *injected* clock, never `time.time()` directly,
+    so tests drive a `ManualClock` and timing output is deterministic.
 """
 from __future__ import annotations
 
@@ -40,7 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint import latest_step, load_checkpoint, save_checkpoint
-from repro.obs import NULL_RECORDER
+from repro.obs import Recorder
 from repro.train.train_step import TrainState
 
 
@@ -50,12 +55,13 @@ class Trainer:
                  ckpt_every: int = 50, keep: int = 3,
                  hbfp=None,  # HBFPConfig | PrecisionSchedule | None
                  controller=None,  # numerics.PrecisionController | None
-                 recorder=None,  # obs.Recorder | None (no-op default)
+                 recorder=None,  # obs.Recorder | None (annotate only)
                  seed: int = 0, background_ckpt: bool = False,
                  state_shardings=None):
         self.train_step = train_step
         self.data_fn = data_fn
-        self.recorder = recorder if recorder is not None else NULL_RECORDER
+        self.recorder = recorder if recorder is not None else Recorder(
+            annotate=jax.profiler.TraceAnnotation)
         if self.recorder.enabled and self.recorder.sync_fn is None:
             # spans around jitted work need a completion barrier; obs is
             # jax-free so the barrier is injected here (DESIGN.md §12)
@@ -104,12 +110,15 @@ class Trainer:
         for step in range(self.start_step, num_steps):
             if fail_at_step is not None and step == fail_at_step:
                 raise RuntimeError(f"simulated preemption at step {step}")
-            batch = self.data_fn(step)
-            key = jax.random.fold_in(jax.random.key(self.seed), step)
             log_now = bool(log_every) and step % log_every == 0
             ljit = {}
             with rec.span("train/step", step=step) as sp:
-                self.state, metrics = self.train_step(self.state, batch, key)
+                with rec.span("train/data", step=step):
+                    batch = self.data_fn(step)
+                    key = jax.random.fold_in(jax.random.key(self.seed), step)
+                with rec.span("train/dispatch", step=step):
+                    self.state, metrics = self.train_step(self.state, batch,
+                                                          key)
                 if log_now:
                     # scalars only (a taps-enabled step's "numerics" aux is
                     # a nested stats pytree — consumed upstream, skipped

@@ -103,6 +103,78 @@ def test_span_records_error_and_still_emits():
     assert "RuntimeError('boom')" in ms.events[0].data["error"]
 
 
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: logs enter and exit."""
+
+    def __init__(self, log, name):
+        self.log, self.name = log, name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+
+
+@pytest.mark.parametrize("with_sinks", [True, False])
+def test_annotate_opens_and_closes_nested_spans_in_order(with_sinks):
+    log = []
+    ms = MemorySink()
+    rec = Recorder([ms] if with_sinks else [],
+                   annotate=lambda name: _FakeAnnotation(log, name))
+    emitted = []
+    emit = rec.emit
+    rec.emit = lambda *a, **kw: emitted.append(a) or emit(*a, **kw)
+    with rec.span("train/step"):
+        with rec.span("train/data"):
+            pass
+        with rec.span("train/dispatch"):
+            pass
+    assert log == [("enter", "repro.train/step"),
+                   ("enter", "repro.train/data"),
+                   ("exit", "repro.train/data"),
+                   ("enter", "repro.train/dispatch"),
+                   ("exit", "repro.train/dispatch"),
+                   ("exit", "repro.train/step")]
+    if with_sinks:
+        assert [e.data["name"] for e in ms.events] == [
+            "train/data", "train/dispatch", "train/step"]
+    else:
+        assert not rec.enabled and emitted == []
+
+
+def test_annotation_closes_when_the_span_raises():
+    log = []
+    rec = Recorder(annotate=lambda name: _FakeAnnotation(log, name))
+    with pytest.raises(RuntimeError):
+        with rec.span("doomed"):
+            raise RuntimeError("boom")
+    assert log == [("enter", "repro.doomed"), ("exit", "repro.doomed")]
+
+
+def test_null_recorder_does_not_annotate():
+    assert NULL_RECORDER.annotate_fn is None
+    assert Recorder().annotate_fn is None
+
+
+def test_obs_imports_no_jax():
+    """obs stays stdlib-only: device sync and profiler annotation are
+    injected by the jax-aware layers."""
+    import subprocess
+    import sys
+    env = dict(os.environ)
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import sys, repro.obs; print(sorted(m for m in sys.modules "
+         "if m == 'jax' or m.startswith('jax.')))"],
+        env=env, capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "[]"
+
+
 # ---------------------------------------------------------------------------
 # sinks
 # ---------------------------------------------------------------------------

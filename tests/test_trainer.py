@@ -127,10 +127,10 @@ def test_trainer_timing_deterministic_with_manual_clock(setup):
                  ckpt_dir=None, recorder=rec)
     tr.run(6, log_every=5, log_fn=lines.append)
 
-    spans = ms.of_kind("span")
+    spans = [e for e in ms.of_kind("span") if e.data["name"] == "train/step"]
     assert len(spans) == 6
-    assert all(e.data["name"] == "train/step" for e in spans)
-    assert all(e.data["dur_us"] == pytest.approx(0.1e6) for e in spans)
+    # the step span covers the batch (0.25s) and the dispatch (0.1s)
+    assert all(e.data["dur_us"] == pytest.approx(0.35e6) for e in spans)
     # sync (block_until_ready stand-in) only on log-cadence steps
     assert [e.data["synced"] for e in spans] == [True, False, False,
                                                 False, False, True]
@@ -141,6 +141,63 @@ def test_trainer_timing_deterministic_with_manual_clock(setup):
     assert prog[1].data["elapsed_s"] == pytest.approx(6 * 0.35)
     assert lines[0].startswith("step      0 ") and "(0.3s)" in lines[0]
     assert "(2.1s)" in lines[1]
+
+
+def test_trainer_step_span_nests_data_and_dispatch(setup):
+    """train/step is the parent of the loop body: train/data (the batch
+    and the key fold) then train/dispatch (the train_step call)."""
+    from repro.obs import ManualClock, MemorySink, Recorder
+    _, pipe, step, state = setup
+    clk = ManualClock()
+    ms = MemorySink()
+    log = []
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    rec = Recorder([ms], clock=clk, sync=lambda x: x, annotate=Ann)
+
+    def data(i):
+        clk.advance(0.25)
+        return pipe.batch(i)
+
+    def stepped(s, b, k):
+        clk.advance(0.1)
+        return step(s, b, k)
+
+    Trainer(train_step=stepped, init_state=state, data_fn=data,
+            ckpt_dir=None, recorder=rec).run(2, log_every=0)
+    spans = [(e.step, e.data["name"], e.data.get("parent"), e.data["depth"],
+              round(e.data["dur_us"])) for e in ms.of_kind("span")]
+    assert spans == [
+        (i, name, parent, depth, dur) for i in range(2)
+        for name, parent, depth, dur in (
+            ("train/data", "train/step", 1, 250000),
+            ("train/dispatch", "train/step", 1, 100000),
+            ("train/step", None, 0, 350000))]
+    assert log == 2 * [("enter", "repro.train/step"),
+                       ("enter", "repro.train/data"),
+                       ("exit", "repro.train/data"),
+                       ("enter", "repro.train/dispatch"),
+                       ("exit", "repro.train/dispatch"),
+                       ("exit", "repro.train/step")]
+
+
+def test_trainer_default_recorder_annotates_without_sinks(setup):
+    """No recorder passed: spans go to the profiler's clock, no events."""
+    _, pipe, step, state = setup
+    tr = Trainer(train_step=step, init_state=state, data_fn=pipe.batch,
+                 ckpt_dir=None)
+    assert not tr.recorder.enabled
+    assert tr.recorder.annotate_fn is jax.profiler.TraceAnnotation
+    assert tr.recorder.sync_fn is None
 
 
 def test_trainer_checkpoint_events_flow_to_recorder(tmp_path, setup):
