@@ -11,7 +11,8 @@ favors MXU-aligned VMEM-resident tiles). This module provides:
     `op/MxKxN/dtype/m<bits>/b<block>` keys to the winning tiles + timings;
   * `lookup(op, M, K, N, ...)` — the trace-time entry point `ops.py` and
     `kernels/linear.py` call when no explicit tiles are given: returns the
-    tuned tiles when the table has the shape, else DEFAULT_TILES clipped;
+    tuned tiles when the table has the shape, else `shape_tiles`, the
+    rule that picks tiles from the GEMM's shape;
   * `autotune_op(...)` — measure every candidate for one op/shape and
     record the winner.
 
@@ -26,15 +27,22 @@ import json
 import os
 from typing import Dict, Iterable, Optional, Tuple
 
+from repro.kernels.common import GROUP, slice_width
+from repro.kernels.hbfp_matmul import vmem_bytes
 from repro.obs import NULL_RECORDER
 from repro.obs.trace import time_fn
 
 Tiles = Tuple[int, int, int]
 
+# today's tiles for exponent groups under 128 (the dequantize-in-VMEM path)
 DEFAULT_TILES: Tiles = (128, 128, 128)
 TILE_MENU: Tuple[int, ...] = (32, 64, 128, 256)
-# ~16 MB VMEM per core; leave headroom for semaphores/regalloc
-VMEM_BUDGET_BYTES = 12 * 2 ** 20
+# the tiles' share of a v5e core's 128 MiB VMEM (hbfp_matmul.vmem_bytes)
+VMEM_BUDGET_BYTES = 64 * 2 ** 20
+# shape rule: the longest contraction edge (8 exponent groups, unrolled in
+# the kernel body) and output edge it picks
+RULE_DEPTH = 1024
+RULE_EDGE = 2048
 TABLE_ENV = "REPRO_AUTOTUNE_TABLE"
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -58,9 +66,15 @@ def cache_key(op: str, M: int, K: int, N: int, dtype: str,
     return f"{op}/{M}x{K}x{N}/{dtype}/m{mantissa_bits}/b{int(block)}"
 
 
+def _padded(d: int) -> int:
+    """A dim as the kernels tile it: itself up to 128, else padded to a
+    multiple of 128 (the exponent group)."""
+    return d if d <= GROUP else -(-d // GROUP) * GROUP
+
+
 def clip_tiles(tiles: Iterable[int], M: int, K: int, N: int) -> Tiles:
-    bm, bk, bn = tiles
-    return (min(int(bm), M), min(int(bk), K), min(int(bn), N))
+    """Tiles no longer than the dims padded to whole exponent groups."""
+    return tuple(min(int(t), _padded(d)) for t, d in zip(tiles, (M, K, N)))
 
 
 def align_tiles(tiles: Iterable[int], block: int) -> Tiles:
@@ -71,12 +85,6 @@ def align_tiles(tiles: Iterable[int], block: int) -> Tiles:
         return tuple(int(t) for t in tiles)
     b = int(block)
     return tuple(-(-int(t) // b) * b for t in tiles)
-
-
-def vmem_bytes(bm: int, bk: int, bn: int, itemsize: int = 4) -> int:
-    """Double-buffered operand blocks + one f32 accumulator scratch."""
-    operands = (bm * bk + bk * bn + bm * bn) * itemsize * 2
-    return operands + bm * bn * 4
 
 
 def candidates(M: int, K: int, N: int, *,
@@ -99,9 +107,9 @@ def candidates(M: int, K: int, N: int, *,
 
 class TuningTable:
     """On-disk tile-tuning table. JSON object: {key: entry} where entry is
-    {"tiles": [bm, bk, bn], "us": winner_us, "default_us": us at
-    DEFAULT_TILES, "speedup": default_us/us, "backend": ..., "n_candidates":
-    ...}. Unknown extra fields are preserved."""
+    {"tiles": [bm, bk, bn], "us": winner_us, "default_us": us at the
+    shape rule's tiles, "speedup": default_us/us, "backend": ...,
+    "n_candidates": ...}. Unknown extra fields are preserved."""
 
     def __init__(self, entries: Optional[Dict[str, dict]] = None,
                  path: Optional[str] = None):
@@ -159,13 +167,64 @@ def invalidate_cache() -> None:
     _CACHED_PATH = None
 
 
+def _edges(d: int, block: int) -> Tuple[int, ...]:
+    """Tile edges for one GEMM dimension, largest first: the dimension
+    itself up to 128, else the multiples of 128 that divide it padded to
+    128 (so no padding beyond the 128 tiles'), of whole exponent groups."""
+    if d <= GROUP:
+        return (d,)
+    n = _padded(d) // GROUP
+    edges = (GROUP * t for t in range(n, 0, -1) if n % t == 0)
+    return tuple(e for e in edges if e % slice_width(block, e) == 0)
+
+
+# which tile of (bm, bk, bn) each op contracts
+_DEPTH = {"matmul_fwd": 1, "matmul_dgrad": 2, "matmul_wgrad": 0}
+
+
+def shape_tiles(op: str, M: int, K: int, N: int, block: int = 0,
+                budget: int = VMEM_BUDGET_BYTES) -> Tiles:
+    """The tiles an untuned GEMM runs at, from its shape alone. The
+    contraction edge is the longest edge up to RULE_DEPTH (at most 8
+    exponent groups a grid step); each output edge the longest up to
+    RULE_EDGE, the larger stepping down until the tile's VMEM
+    (`hbfp_matmul.vmem_bytes`, f32 operands) fits `budget`. Large output
+    edges cut how often each operand block is fetched and re-quantized.
+    Sub-128 exponent groups keep DEFAULT_TILES (their dequantize path
+    gains nothing from long tiles)."""
+    if block and block < GROUP:
+        return clip_tiles(DEFAULT_TILES, M, K, N)
+    dims = (M, K, N)
+    depth = _DEPTH[op]
+    out = [a for a in range(3) if a != depth]   # (rows, cols) of the tile
+    opts = {a: [e for e in _edges(dims[a], block)
+                if e <= (RULE_DEPTH if a == depth else RULE_EDGE)]
+            or [min(_edges(dims[a], block))] for a in range(3)}
+    pick = {a: 0 for a in range(3)}
+
+    def tiles():
+        return tuple(opts[a][pick[a]] for a in range(3))
+
+    def fits():
+        t = tiles()
+        return vmem_bytes(t[out[0]], t[depth], t[out[1]]) <= budget
+
+    while not fits():
+        a = max((a for a in out if pick[a] + 1 < len(opts[a])),
+                key=lambda a: opts[a][pick[a]], default=None)
+        if a is None:
+            break
+        pick[a] += 1
+    return tiles()
+
+
 def lookup(op: str, M: int, K: int, N: int, *, dtype: str = "float32",
            mantissa_bits: int = 8, block: int = 0) -> Tiles:
     """Trace-time tile resolution: tuned tiles if the table has this
-    (op, shape, dtype, m, b) cell, else DEFAULT_TILES — always clipped to
-    the problem so small shapes stay single-block."""
+    (op, shape, dtype, m, b) cell, else the shape rule (`shape_tiles`) —
+    always clipped to the problem so small shapes stay single-block."""
     t = get_table().get(cache_key(op, M, K, N, dtype, mantissa_bits, block))
-    return clip_tiles(t or DEFAULT_TILES, M, K, N)
+    return clip_tiles(t or shape_tiles(op, M, K, N, block), M, K, N)
 
 
 def _time_us(fn, n: int = 3, warmup: int = 1) -> float:
@@ -195,7 +254,7 @@ def autotune_op(op: str, run_fn, M: int, K: int, N: int, *,
     rec = recorder if recorder is not None else NULL_RECORDER
     table = table or get_table()
     cands = candidates(M, K, N, menu=menu)
-    default = clip_tiles(DEFAULT_TILES, M, K, N)
+    default = shape_tiles(op, M, K, N, block)
     if default not in cands:
         cands = (default,) + cands
     key = cache_key(op, M, K, N, dtype, mantissa_bits, block)

@@ -141,6 +141,82 @@ def tile_group_amax(w, block: int):
     return _group_max(_group_max(a, cb, 1), rb, 0)
 
 
+# The GEMM kernels' exponent group at block=0 (DESIGN.md §13): one exponent
+# per row per GROUP contraction columns for activations and gradients, one
+# per GROUP x GROUP sub-tile for weights, whatever the kernel tile (a tile
+# edge under GROUP is one group). Tiles are a speed choice only.
+GROUP = 128
+
+
+def slice_width(block: int, edge: int) -> int:
+    """Contraction columns a GEMM kernel quantizes and contracts per inner
+    step of a tile edge: one exponent group (`block`, or GROUP at
+    block=0) when the group is at least GROUP wide, else GROUP columns
+    holding several smaller groups; never more than the edge."""
+    return min(max(int(block) or GROUP, GROUP), edge)
+
+
+def small_groups(block: int, *edges: int) -> bool:
+    """True when a sub-GROUP `block` splits one of these slice edges into
+    several exponent groups: the scales then vary inside the MXU operand,
+    so the kernels dequantize in VMEM and contract on the f32 MXU."""
+    return bool(block) and block < GROUP and any(block < e for e in edges)
+
+
+def check_slices(block: int, *edges: int) -> None:
+    """Each tile edge must hold whole contraction slices (ValueError)."""
+    for e in edges:
+        if e % slice_width(block, e):
+            raise ValueError(f"tile edge {e} is not a whole number of "
+                             f"{slice_width(block, e)}-wide exponent "
+                             f"groups (block={block})")
+
+
+def group_maxima(a, group: int, axis: int):
+    """Per-group max of the non-negative vector `a` ([1, C] for axis=1,
+    [R, 1] for axis=0) over consecutive `group`-long runs, as a list of
+    [1, 1] arrays — one per weight sub-tile of a contraction slice, from
+    static aligned slices (no masked reductions)."""
+    n = a.shape[axis]
+    if group >= n:
+        return [a.max(axis=axis, keepdims=True)]
+    return [jax.lax.slice_in_dim(a, o, o + group, axis=axis)
+            .max(axis=axis, keepdims=True) for o in range(0, n, group)]
+
+
+def spread(maxima, group: int, axis: int):
+    """`group_maxima`'s list broadcast back along `axis` ([1, n·group] for
+    axis=1, [n·group, 1] for axis=0); one group stays [1, 1]."""
+    if len(maxima) == 1:
+        return maxima[0]
+    shape = (1, group) if axis == 1 else (group, 1)
+    return jnp.concatenate([jnp.broadcast_to(m, shape) for m in maxima],
+                           axis=axis)
+
+
+def bfp_step(amax, mantissa_bits: int):
+    """The quantization step δ for a (broadcastable) amax, exactly as
+    `quantize_block` computes it."""
+    return pow2(max_exponent(amax) - mantissa_bits + 2)
+
+
+def dequantize_rows(x, mantissa_bits: int, block: int, *, stochastic: bool,
+                    seed=None, idx=None):
+    """Q(x)·δ for a 2-D activation or gradient block: one exponent per row
+    per exponent group of the last axis, taken slice by slice
+    (`slice_width`; sub-GROUP blocks refine inside each slice). Values
+    are exact in f32 for m ≤ 12 — the wgrad kernel's operand format."""
+    s = slice_width(block, x.shape[1])
+    parts = []
+    for o in range(0, x.shape[1], s):
+        xs = x[:, o:o + s]
+        q, d = quantize_block(xs, mantissa_bits, row_group_amax(xs, block),
+                              stochastic=stochastic, seed=seed,
+                              idx=None if idx is None else idx[:, o:o + s])
+        parts.append(q * d)
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
 def quantize_block(x, mantissa_bits: int, amax, *, stochastic: bool,
                    seed=None, idx=None, with_clip: bool = False):
     """Quantize x against per-element broadcastable amax. Returns (q, delta)

@@ -13,17 +13,22 @@ Each GEMM quantizes its operands at its own tiling right before the dot
 (the paper's conversion-fused-into-MatMul rule; FlexBlock's per-GEMM BFP
 modes) — x and dy draw from the same stochastic stream in every GEMM they
 appear in (kernels/common.py STREAM_*), so matching tilings re-quantize to
-identical values. Tile sizes resolve per GEMM through the autotuner table
-at trace time (kernels/autotune.py). Non-divisible shapes pad to the tile
-grid and slice back; zero padding quantizes to zero and contributes
-nothing to any of the three contractions.
+identical values. Tile sizes resolve per GEMM at trace time through the
+autotuner table, else the shape rule (kernels/autotune.py); they change
+speed only, never the exponent groups (kernels/hbfp_matmul.py). Each
+resolution emits a `kernel/gemm` event (op, shape, tiles, path) to the
+recorder of `gemm_events`. Non-divisible shapes pad to the tile grid and
+slice back; zero padding quantizes to zero and contributes nothing to any
+of the three contractions.
 
 See docs/KERNELS.md for the dataflow diagrams and DESIGN.md §10 for the
 backward-pass numerics rationale.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -32,8 +37,9 @@ import numpy as np
 
 from repro.kernels import autotune, ops
 from repro.kernels.common import role_stream_salt
-from repro.kernels.hbfp_matmul import (hbfp_dgrad_pallas, hbfp_matmul_pallas,
-                                       hbfp_wgrad_pallas)
+from repro.kernels.hbfp_matmul import (gemm_path, hbfp_dgrad_pallas,
+                                       hbfp_matmul_pallas, hbfp_wgrad_pallas)
+from repro.obs import NULL_RECORDER
 
 
 class KernelSpec(NamedTuple):
@@ -64,11 +70,16 @@ def _zero_cotangent(x):
     return np.zeros(np.shape(x), jax.dtypes.float0)
 
 
+def _tiles(tiles, block: int, M: int, K: int, N: int):
+    """The tiles a kernel runs at: the spec's, clipped to the problem and
+    rounded up to whole exponent blocks."""
+    return autotune.align_tiles(autotune.clip_tiles(tiles, M, K, N), block)
+
+
 def _fwd_impl(spec: KernelSpec, x2, w, seed):
     M, K = x2.shape
     N = w.shape[1]
-    bm, bk, bn = autotune.align_tiles(
-        autotune.clip_tiles(spec.fwd, M, K, N), spec.block)
+    bm, bk, bn = _tiles(spec.fwd, spec.block, M, K, N)
     y = hbfp_matmul_pallas(
         _pad2(x2, bm, bk), _pad2(w, bk, bn), seed,
         mantissa_bits=spec.mantissa_bits, stochastic=spec.stochastic,
@@ -105,8 +116,7 @@ def _vjp_bwd(spec, res, g):
     m_w = spec.m_wgrad or spec.mantissa_bits
     g = g.astype(jnp.float32)
     # dgrad: dx[M,K] = Q(g)·Q(w)^T, contraction over N
-    bm, bk, bn = autotune.align_tiles(
-        autotune.clip_tiles(spec.dgrad, M, K, N), spec.block)
+    bm, bk, bn = _tiles(spec.dgrad, spec.block, M, K, N)
     dx = hbfp_dgrad_pallas(
         _pad2(g, bm, bn), _pad2(w, bk, bn),
         _role_seed(seed, "dgrad", m_d, spec.mantissa_bits,
@@ -115,8 +125,7 @@ def _vjp_bwd(spec, res, g):
         quantize_w=spec.quantize_w, block=spec.block, bm=bm, bk=bk, bn=bn,
         interpret=ops.interpret())[:M, :K]
     # wgrad: dw[K,N] = Q(x)^T·Q(g), contraction over the token axis M
-    bm, bk, bn = autotune.align_tiles(
-        autotune.clip_tiles(spec.wgrad, M, K, N), spec.block)
+    bm, bk, bn = _tiles(spec.wgrad, spec.block, M, K, N)
     dw = hbfp_wgrad_pallas(
         _pad2(x2, bm, bk), _pad2(g, bm, bn),
         _role_seed(seed, "wgrad", m_w, spec.mantissa_bits,
@@ -137,22 +146,43 @@ def seed_from_key(key) -> jax.Array:
     return (kd[0] ^ kd[-1]).astype(jnp.int32).reshape(1, 1)
 
 
+_EVENTS = threading.local()
+
+
+@contextlib.contextmanager
+def gemm_events(recorder):
+    """Send `resolve_spec`'s trace-time `kernel/gemm` events to `recorder`
+    inside the block (a compile logs each call site's GEMMs once; a
+    cached trace logs nothing). `make_step` wraps its step calls in it."""
+    prev = getattr(_EVENTS, "recorder", None)
+    _EVENTS.recorder = recorder
+    try:
+        yield recorder
+    finally:
+        _EVENTS.recorder = prev
+
+
 def resolve_spec(cfg, M: int, K: int, N: int,
                  dtype: str = "float32",
                  dgrad_cfg=None, wgrad_cfg=None) -> KernelSpec:
     """Build the static KernelSpec for one call site: rounding/width from
-    the HBFPConfig, per-GEMM tiles from the autotuner table (trace time).
+    the HBFPConfig, per-GEMM tiles from the autotuner table or the shape
+    rule (trace time).
     `dgrad_cfg`/`wgrad_cfg` carry per-role widths (DESIGN.md §11); each
     GEMM's tile lookup is keyed by its own role width, so a "wgrad+2"
     policy consults the m-matched autotune cells (docs/KERNELS.md). The
     config's schedulable block size (`HBFPConfig.act_block`, set by
     `with_block`; DESIGN.md §13) becomes `KernelSpec.block` and keys every
     tile lookup — sub-block scales change the kernel dataflow, so tuned
-    tiles don't transfer across block sizes."""
+    tiles don't transfer across block sizes.
+
+    Emits one `kernel/gemm` event per GEMM (fwd, dgrad, wgrad) to the
+    recorder `gemm_events` set: op, logical shape, the tiles it runs at
+    and its contraction path (`gemm_path`)."""
     m_d = (dgrad_cfg or cfg).mantissa_bits
     m_w = (wgrad_cfg or cfg).mantissa_bits
     block = int(getattr(cfg, "act_block", None) or 0)
-    return KernelSpec(
+    spec = KernelSpec(
         mantissa_bits=cfg.mantissa_bits,
         stochastic=cfg.rounding == "stochastic",
         quantize_w=cfg.requantize_weights,
@@ -165,6 +195,18 @@ def resolve_spec(cfg, M: int, K: int, N: int,
         m_dgrad=0 if m_d == cfg.mantissa_bits else m_d,
         m_wgrad=0 if m_w == cfg.mantissa_bits else m_w,
         block=block)
+    rec = getattr(_EVENTS, "recorder", None) or NULL_RECORDER
+    if rec.enabled:
+        for op, tiles, m in (("matmul_fwd", spec.fwd, cfg.mantissa_bits),
+                             ("matmul_dgrad", spec.dgrad, m_d),
+                             ("matmul_wgrad", spec.wgrad, m_w)):
+            t = _tiles(tiles, block, M, K, N)
+            rec.emit("kernel/gemm", op=op, shape=[M, K, N], tiles=list(t),
+                     path=gemm_path(op, mantissa_bits=m,
+                                    quantize_w=spec.quantize_w, block=block,
+                                    tiles=t),
+                     mantissa_bits=m, block=block)
+    return spec
 
 
 def hbfp_matmul_kernel(x: jax.Array, w: jax.Array, cfg,

@@ -9,8 +9,8 @@ Mosaic refuses raises instead of falling back.
 
 Tile sizes: pass bm/bk/bn explicitly to pin them, or leave None and the
 wrapper resolves them at trace time from the autotuner table
-(kernels/autotune.py; default (128,128,128) clipped when the shape is
-untuned).
+(kernels/autotune.py; the shape rule `shape_tiles` when the shape is
+untuned). Tiles change speed, not the exponent groups.
 """
 from __future__ import annotations
 
@@ -43,10 +43,9 @@ def _tiles(op, bm, bk, bn, M, K, N, mantissa_bits, dtype="float32",
     if bm is None or bk is None or bn is None:
         t = autotune.lookup(op, M, K, N, dtype=dtype,
                             mantissa_bits=mantissa_bits, block=block)
-        return (t[0] if bm is None else min(bm, M),
-                t[1] if bk is None else min(bk, K),
-                t[2] if bn is None else min(bn, N))
-    return min(bm, M), min(bk, K), min(bn, N)
+        bm, bk, bn = (t[0] if bm is None else bm, t[1] if bk is None else bk,
+                      t[2] if bn is None else bn)
+    return autotune.clip_tiles((bm, bk, bn), M, K, N)
 
 
 def bfp_quantize(x, seed=0, *, mantissa_bits=8, tile=128, stochastic=False,
@@ -81,9 +80,9 @@ def hbfp_matmul(x, w, seed=None, *, mantissa_bits=8, stochastic=False,
 
     Pads every dim to the tile size (zero rows/cols quantize to zero and
     contribute nothing), calls the kernel, slices back. Tiles default to
-    the autotuner table for the logical shape. `block` (0 ⇒ whole-tile)
-    selects the exponent-block granularity inside each kernel tile
-    (DESIGN.md §13) and keys its own autotune cell.
+    the autotuner table for the logical shape. `block` (0 ⇒ groups of
+    128) selects the exponent-block granularity (DESIGN.md §13) and keys
+    its own autotune cell.
     """
     lead = x.shape[:-2] if x.ndim > 2 else ()
     M0, K0 = x.shape[-2], x.shape[-1]

@@ -10,8 +10,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.common import (STREAM_G, STREAM_W, STREAM_X,
-                                  quantize_block, row_group_amax,
+from repro.kernels.common import (GROUP, STREAM_G, STREAM_W, STREAM_X,
+                                  dequantize_rows, quantize_block,
+                                  row_group_amax, slice_width, small_groups,
                                   tile_group_amax)
 
 
@@ -58,73 +59,62 @@ def bfp_quantize_ref(x, seed, *, mantissa_bits=8, tile_r=128, tile_c=128,
 def hbfp_matmul_ref(x, w, seed=None, *, mantissa_bits=8, stochastic=False,
                     quantize_w=True, block=0, bm=128, bk=128, bn=128,
                     out_dtype=jnp.float32):
-    """Oracle for hbfp_matmul_pallas: per-(row, K-block) activation exponents,
-    per-(bk, bn)-tile weight exponents, f32 accumulation across K blocks.
-    quantize_w=False mirrors the kernel's pre-narrowed-weight path (raw w,
-    f32 contraction). block>0 refines exponents to per-(row, block-group)
-    for x and (block, block) sub-tiles for w — the kernel's schedulable
-    block size (DESIGN.md §13)."""
+    """Oracle for hbfp_matmul_pallas: per-(row, exponent group) activation
+    exponents, per-sub-tile weight exponents (128 x 128 at block=0, clamped
+    to the tile), f32 accumulation over the groups of K in ascending
+    order. quantize_w=False mirrors the kernel's pre-narrowed-weight path
+    (raw w, f32 contraction). block>0 sets the group: per-(row, block)
+    for x and (block, block) sub-tiles for w (DESIGN.md §13)."""
     M, K = x.shape
     _, N = w.shape
-    bm_, bk_, bn_ = min(bm, M), min(bk, K), min(bn, N)
-    x_sub = bool(block) and block < bk_
-    w_sub = bool(block) and (block < bk_ or block < bn_)
+    bk_, bn_ = min(bk, K), min(bn, N)
+    s = slice_width(block, bk_)
+    x_sub = small_groups(block, s)
+    w_sub = small_groups(block, s, bn_)
+    cw = bn_ if w_sub or not quantize_w else min(block or GROUP, bn_)
     seed_v = jnp.zeros((), jnp.int32) if seed is None \
         else jnp.asarray(seed).reshape(-1)[0]
     xf = x.astype(jnp.float32)
     wf = w.astype(jnp.float32)
 
     acc = jnp.zeros((M, N), jnp.float32)
-    for kk in range(K // bk_):
-        xs = xf[:, kk * bk_:(kk + 1) * bk_]                      # [M, bk]
+    for kk in range(K // s):
+        xs = xf[:, kk * s:(kk + 1) * s]                          # [M, s]
         ax = row_group_amax(xs, block)
         idx_x = None
         if stochastic:
-            r = jax.lax.broadcasted_iota(jnp.int32, (M, bk_), 0)
-            c = jax.lax.broadcasted_iota(jnp.int32, (M, bk_), 1)
-            idx_x = r * K + (kk * bk_ + c) + jnp.int32(STREAM_X)
+            r = jax.lax.broadcasted_iota(jnp.int32, (M, s), 0)
+            c = jax.lax.broadcasted_iota(jnp.int32, (M, s), 1)
+            idx_x = r * K + (kk * s + c) + jnp.int32(STREAM_X)
         qx, dx = quantize_block(xs, mantissa_bits, ax, stochastic=stochastic,
                                 seed=seed_v, idx=idx_x)
-        for jj in range(N // bn_):
-            ws = wf[kk * bk_:(kk + 1) * bk_, jj * bn_:(jj + 1) * bn_]
+        for jj in range(N // cw):
+            ws = wf[kk * s:(kk + 1) * s, jj * cw:(jj + 1) * cw]
+            cols = slice(jj * cw, (jj + 1) * cw)
             if not quantize_w:
                 if x_sub:
-                    part = jax.lax.dot_general(
-                        qx * dx, ws, (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-                    acc = acc.at[:, jj * bn_:(jj + 1) * bn_].add(part)
+                    part = _dot(qx * dx, ws, (1,), (0,))
+                    acc = acc.at[:, cols].add(part)
                 else:
-                    part = jax.lax.dot_general(
-                        qx, ws, (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-                    acc = acc.at[:, jj * bn_:(jj + 1) * bn_].add(part * dx)
+                    part = _dot(qx, ws, (1,), (0,))
+                    acc = acc.at[:, cols].add(part * dx)
                 continue
             aw = tile_group_amax(ws, block if w_sub else 0)
             idx_w = None
             if stochastic:
-                rw = jax.lax.broadcasted_iota(jnp.int32, (bk_, bn_), 0)
-                cw = jax.lax.broadcasted_iota(jnp.int32, (bk_, bn_), 1)
-                idx_w = ((kk * bk_ + rw) * N + (jj * bn_ + cw)
+                rw = jax.lax.broadcasted_iota(jnp.int32, (s, cw), 0)
+                cl = jax.lax.broadcasted_iota(jnp.int32, (s, cw), 1)
+                idx_w = ((kk * s + rw) * N + (jj * cw + cl)
                          + jnp.int32(STREAM_W))
             qw, dw = quantize_block(ws, mantissa_bits, aw,
                                     stochastic=stochastic, seed=seed_v,
                                     idx=idx_w)
-            if x_sub or w_sub:
-                part = jax.lax.dot_general(
-                    qx * dx, qw * dw, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                acc = acc.at[:, jj * bn_:(jj + 1) * bn_].add(part)
+            if w_sub:
+                acc = acc.at[:, cols].add(
+                    _dot(qx * dx, qw * dw, (1,), (0,)))
                 continue
-            if mantissa_bits <= 8:
-                part = jax.lax.dot_general(
-                    qx.astype(jnp.int8), qw.astype(jnp.int8),
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.int32).astype(jnp.float32)
-            else:
-                part = jax.lax.dot_general(
-                    qx, qw, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            acc = acc.at[:, jj * bn_:(jj + 1) * bn_].add(part * (dx * dw))
+            part = _dot(qx, qw, (1,), (0,), int8=mantissa_bits <= 8)
+            acc = acc.at[:, cols].add(part * (dx * dw))
     return acc.astype(out_dtype)
 
 
@@ -132,78 +122,67 @@ def hbfp_dgrad_ref(g, w, seed=None, *, mantissa_bits=8, stochastic=False,
                    quantize_w=True, block=0, bm=128, bk=128, bn=128,
                    out_dtype=jnp.float32):
     """Oracle for hbfp_dgrad_pallas: dx[M,K] = Q(g)·Q(w)^T, gradient rows
-    quantized per (row, N-block), weight tiles per (bk, bn) block of w,
-    f32 accumulation across N blocks in kernel order. block>0 refines the
-    exponent granularity exactly like hbfp_matmul_ref."""
+    quantized per (row, exponent group of N), weight sub-tiles as in the
+    forward, f32 accumulation over the groups of N in kernel order. block>0
+    sets the group exactly like hbfp_matmul_ref."""
     M, N = g.shape
     K, _ = w.shape
-    bm_, bk_, bn_ = min(bm, M), min(bk, K), min(bn, N)
-    g_sub = bool(block) and block < bn_
-    w_sub = bool(block) and (block < bk_ or block < bn_)
+    bk_, bn_ = min(bk, K), min(bn, N)
+    s = slice_width(block, bn_)
+    g_sub = small_groups(block, s)
+    w_sub = small_groups(block, s, bk_)
+    rw_ = bk_ if w_sub or not quantize_w else min(block or GROUP, bk_)
     seed_v = jnp.zeros((), jnp.int32) if seed is None \
         else jnp.asarray(seed).reshape(-1)[0]
     gf = g.astype(jnp.float32)
     wf = w.astype(jnp.float32)
 
     acc = jnp.zeros((M, K), jnp.float32)
-    for nn in range(N // bn_):
-        gs = gf[:, nn * bn_:(nn + 1) * bn_]                      # [M, bn]
+    for nn in range(N // s):
+        gs = gf[:, nn * s:(nn + 1) * s]                          # [M, s]
         ag = row_group_amax(gs, block)
         idx_g = None
         if stochastic:
-            r = jax.lax.broadcasted_iota(jnp.int32, (M, bn_), 0)
-            c = jax.lax.broadcasted_iota(jnp.int32, (M, bn_), 1)
-            idx_g = r * N + (nn * bn_ + c) + jnp.int32(STREAM_G)
+            r = jax.lax.broadcasted_iota(jnp.int32, (M, s), 0)
+            c = jax.lax.broadcasted_iota(jnp.int32, (M, s), 1)
+            idx_g = r * N + (nn * s + c) + jnp.int32(STREAM_G)
         qg, dg = quantize_block(gs, mantissa_bits, ag, stochastic=stochastic,
                                 seed=seed_v, idx=idx_g)
-        for jj in range(K // bk_):
-            ws = wf[jj * bk_:(jj + 1) * bk_, nn * bn_:(nn + 1) * bn_]
+        for jj in range(K // rw_):
+            ws = wf[jj * rw_:(jj + 1) * rw_, nn * s:(nn + 1) * s]
+            cols = slice(jj * rw_, (jj + 1) * rw_)
             if not quantize_w:
                 if g_sub:
-                    part = jax.lax.dot_general(
-                        qg * dg, ws, (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-                    acc = acc.at[:, jj * bk_:(jj + 1) * bk_].add(part)
+                    part = _dot(qg * dg, ws, (1,), (1,))
+                    acc = acc.at[:, cols].add(part)
                 else:
-                    part = jax.lax.dot_general(
-                        qg, ws, (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-                    acc = acc.at[:, jj * bk_:(jj + 1) * bk_].add(part * dg)
+                    part = _dot(qg, ws, (1,), (1,))
+                    acc = acc.at[:, cols].add(part * dg)
                 continue
             aw = tile_group_amax(ws, block if w_sub else 0)
             idx_w = None
             if stochastic:
-                rw = jax.lax.broadcasted_iota(jnp.int32, (bk_, bn_), 0)
-                cw = jax.lax.broadcasted_iota(jnp.int32, (bk_, bn_), 1)
-                idx_w = ((jj * bk_ + rw) * N + (nn * bn_ + cw)
+                rw = jax.lax.broadcasted_iota(jnp.int32, (rw_, s), 0)
+                cw = jax.lax.broadcasted_iota(jnp.int32, (rw_, s), 1)
+                idx_w = ((jj * rw_ + rw) * N + (nn * s + cw)
                          + jnp.int32(STREAM_W))
             qw, dw = quantize_block(ws, mantissa_bits, aw,
                                     stochastic=stochastic, seed=seed_v,
                                     idx=idx_w)
-            if g_sub or w_sub:
-                part = jax.lax.dot_general(
-                    qg * dg, qw * dw, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                acc = acc.at[:, jj * bk_:(jj + 1) * bk_].add(part)
+            if w_sub:
+                acc = acc.at[:, cols].add(
+                    _dot(qg * dg, qw * dw, (1,), (1,)))
                 continue
-            if mantissa_bits <= 8:
-                part = jax.lax.dot_general(
-                    qg.astype(jnp.int8), qw.astype(jnp.int8),
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.int32).astype(jnp.float32)
-            else:
-                part = jax.lax.dot_general(
-                    qg, qw, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            acc = acc.at[:, jj * bk_:(jj + 1) * bk_].add(part * (dg * dw))
+            part = _dot(qg, qw, (1,), (1,), int8=mantissa_bits <= 8)
+            acc = acc.at[:, cols].add(part * (dg * dw))
     return acc.astype(out_dtype)
 
 
 def hbfp_wgrad_ref(x, g, seed=None, *, mantissa_bits=8, stochastic=False,
                    block=0, bm=128, bk=128, bn=128, out_dtype=jnp.float32):
     """Oracle for hbfp_wgrad_pallas: dw[K,N] = Q(x)^T·Q(g). Both operands
-    take per-(row, block) activation exponents (x over K-blocks on the
-    forward's stream, g over N-blocks on the dgrad stream); per-token scales
+    take per-(row, exponent group) activation exponents (x over K on the
+    forward's stream, g over N on the dgrad stream); per-token scales
     ride the contraction, so dequantized f32 outer products accumulate in
     kernel order over M blocks."""
     M, K = x.shape
@@ -214,40 +193,42 @@ def hbfp_wgrad_ref(x, g, seed=None, *, mantissa_bits=8, stochastic=False,
     xf = x.astype(jnp.float32)
     gf = g.astype(jnp.float32)
 
+    def idx(mm, c0, rows, cols, stride, stream):
+        if not stochastic:
+            return None
+        r = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+        c = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+        return (mm * rows + r) * stride + (c0 + c) + jnp.int32(stream)
+
     acc = jnp.zeros((K, N), jnp.float32)
     for mm in range(M // bm_):
         xs = xf[mm * bm_:(mm + 1) * bm_]                         # [bm, K]
         gs = gf[mm * bm_:(mm + 1) * bm_]                         # [bm, N]
         for ii in range(K // bk_):
-            xb = xs[:, ii * bk_:(ii + 1) * bk_]
-            ax = row_group_amax(xb, block)
-            idx_x = None
-            if stochastic:
-                r = jax.lax.broadcasted_iota(jnp.int32, (bm_, bk_), 0)
-                c = jax.lax.broadcasted_iota(jnp.int32, (bm_, bk_), 1)
-                idx_x = ((mm * bm_ + r) * K + (ii * bk_ + c)
-                         + jnp.int32(STREAM_X))
-            qx, dx = quantize_block(xb, mantissa_bits, ax,
-                                    stochastic=stochastic, seed=seed_v,
-                                    idx=idx_x)
+            xh = dequantize_rows(
+                xs[:, ii * bk_:(ii + 1) * bk_], mantissa_bits, block,
+                stochastic=stochastic, seed=seed_v,
+                idx=idx(mm, ii * bk_, bm_, bk_, K, STREAM_X))
             for jj in range(N // bn_):
-                gb = gs[:, jj * bn_:(jj + 1) * bn_]
-                ag = row_group_amax(gb, block)
-                idx_g = None
-                if stochastic:
-                    rg = jax.lax.broadcasted_iota(jnp.int32, (bm_, bn_), 0)
-                    cg = jax.lax.broadcasted_iota(jnp.int32, (bm_, bn_), 1)
-                    idx_g = ((mm * bm_ + rg) * N + (jj * bn_ + cg)
-                             + jnp.int32(STREAM_G))
-                qg, dg = quantize_block(gb, mantissa_bits, ag,
-                                        stochastic=stochastic, seed=seed_v,
-                                        idx=idx_g)
-                part = jax.lax.dot_general(
-                    qx * dx, qg * dg, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
+                gh = dequantize_rows(
+                    gs[:, jj * bn_:(jj + 1) * bn_], mantissa_bits, block,
+                    stochastic=stochastic, seed=seed_v,
+                    idx=idx(mm, jj * bn_, bm_, bn_, N, STREAM_G))
                 acc = acc.at[ii * bk_:(ii + 1) * bk_,
-                             jj * bn_:(jj + 1) * bn_].add(part)
+                             jj * bn_:(jj + 1) * bn_].add(
+                    _dot(xh, gh, (0,), (0,)))
     return acc.astype(out_dtype)
+
+
+def _dot(a, b, ca, cb, int8=False):
+    """The kernels' MXU contraction: int8 mantissas with an exact int32
+    accumulate, or f32."""
+    if int8:
+        return jax.lax.dot_general(
+            a.astype(jnp.int8), b.astype(jnp.int8), ((ca, cb), ((), ())),
+            preferred_element_type=jnp.int32).astype(jnp.float32)
+    return jax.lax.dot_general(a, b, ((ca, cb), ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
 def hbfp_flash_attn_ref(q, k, v, *, m_bits=8, m_qk=0, m_pv=0, bq=128,

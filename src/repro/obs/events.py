@@ -46,6 +46,7 @@ KINDS: Dict[str, str] = {
     "precision/decision": "controller widen/narrow decision + signals",
     "autotune/search": "kernel tile search started for one op/shape",
     "autotune/winner": "kernel tile search winner + speedup",
+    "kernel/gemm": "a GEMM call site traced: op, shape, tiles, MXU path",
     "ckpt/save": "checkpoint written (step, dur_s, bytes, packed)",
     "ckpt/load": "checkpoint restored (step, dur_s, bytes)",
     "serve/admit": "request admitted into a lane (prefill done)",

@@ -280,7 +280,8 @@ def make_step(arch: ArchConfig, policy, schedule, *,
         widths) on every tap-cadence collection — with or without a
         controller — and the controller's `"precision/decision"` events
         (the controller picks up this recorder unless it already has
-        one). Emission is host-side and after the step call: the compiled
+        one), and a `"kernel/gemm"` per Pallas GEMM when a step traces
+        (op, shape, tiles, MXU path). Emission is host-side: the compiled
         computation is bit-identical with or without a recorder.
 
     `metrics` gains "mantissa_bits" (the segment's global width, 0 for
@@ -288,6 +289,7 @@ def make_step(arch: ArchConfig, policy, schedule, *,
     Attributes on the returned fn: `.policy`, `.variants`, `.controller`,
     `.buffer`, `.tap`. Extra kwargs forward to `make_train_step`.
     """
+    from repro.kernels.linear import gemm_events
     from repro.obs import NULL_RECORDER
     rec = recorder if recorder is not None else NULL_RECORDER
     pol = as_policy(policy, backend=arch.kernel_backend)
@@ -344,7 +346,9 @@ def make_step(arch: ArchConfig, policy, schedule, *,
             # the controller's override state names the current adaptive
             # "segment"; decisions take effect at the next step
             seg = seg.with_controller(controller.overrides())
-        state, metrics = variant(seg, telemetry, step)(state, batch, key)
+        # a first call traces: each kernel GEMM logs its tiles and path
+        with gemm_events(rec):
+            state, metrics = variant(seg, telemetry, step)(state, batch, key)
         metrics = dict(metrics)
         if telemetry and (controller is not None or rec.enabled):
             from repro.numerics.stats import stats_to_host

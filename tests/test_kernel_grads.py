@@ -16,8 +16,11 @@ from repro.core import HBFPConfig
 from repro.core.hbfp_ops import hbfp_matmul as sim_matmul
 from repro.kernels import autotune, ops, ref
 from repro.kernels.hbfp_matmul import hbfp_dgrad_pallas, hbfp_wgrad_pallas
-from repro.kernels.linear import hbfp_matmul_kernel, seed_from_key
+from repro.kernels.hbfp_matmul import vmem_bytes
+from repro.kernels.linear import (gemm_events, hbfp_matmul_kernel,
+                                  resolve_spec, seed_from_key)
 from repro.models.layers import Ctx, ctx_matmul
+from repro.obs import MemorySink, Recorder
 
 BWD_CASES = [
     # (M, K, N, bm, bk, bn)
@@ -131,7 +134,8 @@ def test_custom_vjp_grads_match_ref_oracles(rounding):
     which the stochastic streams depend on)."""
     cfg = HBFPConfig(8, 16, rounding=rounding)
     key = jax.random.key(11)
-    M, K, N = 150, 72, 60  # M pads 150 -> 256 at the default bm=128
+    M, K, N = 150, 72, 60  # M pads 150 -> 256, as with a 128 tile
+    spec = resolve_spec(cfg, M, K, N)
     x = jax.random.normal(jax.random.key(0), (M, K))
     w = jax.random.normal(jax.random.key(1), (K, N)) * 0.1
 
@@ -146,10 +150,12 @@ def test_custom_vjp_grads_match_ref_oracles(rounding):
     st = rounding == "stochastic"
     gp = jnp.pad(g, ((0, 256 - M), (0, 0)))
     xp = jnp.pad(x, ((0, 256 - M), (0, 0)))
-    dxr = ref.hbfp_dgrad_ref(gp, w, seed, mantissa_bits=8,
-                             stochastic=st)[:M, :K]
-    dwr = ref.hbfp_wgrad_ref(xp, gp, seed, mantissa_bits=8,
-                             stochastic=st)[:K, :N]
+    bm, bk, bn = spec.dgrad
+    dxr = ref.hbfp_dgrad_ref(gp, w, seed, mantissa_bits=8, stochastic=st,
+                             bm=bm, bk=bk, bn=bn)[:M, :K]
+    bm, bk, bn = spec.wgrad  # the token tile sets wgrad's f32 sum order
+    dwr = ref.hbfp_wgrad_ref(xp, gp, seed, mantissa_bits=8, stochastic=st,
+                             bm=bm, bk=bk, bn=bn)[:K, :N]
     np.testing.assert_array_equal(np.asarray(dx), np.asarray(dxr))
     np.testing.assert_array_equal(np.asarray(dw), np.asarray(dwr))
 
@@ -272,8 +278,9 @@ def test_autotune_table_roundtrip_and_lookup(tmp_path, monkeypatch):
     path = str(tmp_path / "tune.json")
     monkeypatch.setenv(autotune.TABLE_ENV, path)
     autotune.invalidate_cache()
-    # untuned ⇒ default, clipped
-    assert autotune.lookup("matmul_fwd", 64, 256, 512) == (64, 128, 128)
+    # untuned ⇒ the shape rule: whole dims up to the rule's edges
+    assert autotune.lookup("matmul_fwd", 64, 256, 512) == (64, 256, 512)
+    assert autotune.shape_tiles("matmul_fwd", 64, 256, 512) == (64, 256, 512)
     t = autotune.TuningTable.load()
     key = autotune.cache_key("matmul_fwd", 64, 256, 512, "float32", 8)
     t.put(key, (32, 64, 256), us=1.0, speedup=2.0)
@@ -282,7 +289,7 @@ def test_autotune_table_roundtrip_and_lookup(tmp_path, monkeypatch):
     assert autotune.lookup("matmul_fwd", 64, 256, 512) == (32, 64, 256)
     # different mantissa width is a different cell ⇒ default again
     assert autotune.lookup("matmul_fwd", 64, 256, 512,
-                           mantissa_bits=12) == (64, 128, 128)
+                           mantissa_bits=12) == (64, 256, 512)
     autotune.invalidate_cache()
 
 
@@ -504,3 +511,98 @@ def test_train_step_pallas_stochastic_rounding():
     state = init_train_state(jax.random.key(0), arch, init_params)
     _, m = step(state, _batch(), jax.random.key(3))
     assert np.isfinite(float(m["loss"]))
+
+
+# weight GEMMs (M, K, N) of the benchmark's configurations at B·S = 4096
+# tokens (the head on loss chunks of 2048): yi-9b-4L (q/o, k/v, FFN up
+# and down, head) and minicpm-2b-10L
+BENCH_GEMMS = {
+    "yi.attn_qo": (4096, 4096, 4096), "yi.attn_kv": (4096, 4096, 512),
+    "yi.ffn_up": (4096, 4096, 11008), "yi.ffn_down": (4096, 11008, 4096),
+    "yi.head": (2048, 4096, 8000),
+    "minicpm.attn": (4096, 2304, 2304), "minicpm.ffn_up": (4096, 2304, 5760),
+    "minicpm.ffn_down": (4096, 5760, 2304),
+    "minicpm.head": (2048, 2304, 15344),
+}
+# (rows, depth, cols) of each op's (bm, bk, bn) tiles
+_TILE_ROLES = {"matmul_fwd": (0, 1, 2), "matmul_dgrad": (0, 2, 1),
+               "matmul_wgrad": (1, 0, 2)}
+
+
+def _gemm_events(cfg, M, K, N):
+    sink = MemorySink()
+    with gemm_events(Recorder([sink])):
+        spec = resolve_spec(cfg, M, K, N)
+    evs = {e.data["op"]: e.data for e in sink.events
+           if e.kind == "kernel/gemm"}
+    return spec, evs
+
+
+@pytest.mark.parametrize("gemm", sorted(BENCH_GEMMS))
+def test_shape_rule_tiles_and_paths(gemm):
+    """Untuned tiles are multiples of 128 that divide the 128-padded dims
+    (no padding beyond a 128 tile's) and fit the VMEM budget; the trace-
+    time event reports them, fwd and dgrad on the int8 per-group path.
+    block=16 keeps today's tiles on the f32 dequantize path."""
+    M, K, N = BENCH_GEMMS[gemm]
+    padded = [-(-d // 128) * 128 for d in (M, K, N)]
+    spec, evs = _gemm_events(HBFPConfig(8, 16), M, K, N)
+    for op, tiles in (("matmul_fwd", spec.fwd), ("matmul_dgrad", spec.dgrad),
+                      ("matmul_wgrad", spec.wgrad)):
+        assert all(t % 128 == 0 and p % t == 0
+                   for t, p in zip(tiles, padded)), (op, tiles)
+        assert vmem_bytes(*(tiles[a] for a in _TILE_ROLES[op])) \
+            <= autotune.VMEM_BUDGET_BYTES
+        assert tiles[_TILE_ROLES[op][1]] <= autotune.RULE_DEPTH
+        assert evs[op]["tiles"] == list(tiles)
+        assert evs[op]["shape"] == [M, K, N]
+    assert evs["matmul_fwd"]["path"] == "int8_group"
+    assert evs["matmul_dgrad"]["path"] == "int8_group"
+    assert evs["matmul_wgrad"]["path"] == "f32_wgrad"
+    spec16, evs16 = _gemm_events(HBFPConfig(8, 16).with_block(16), M, K, N)
+    assert spec16.fwd == spec16.dgrad == spec16.wgrad == (128, 128, 128)
+    assert evs16["matmul_fwd"]["path"] == "f32_group"
+    assert evs16["matmul_dgrad"]["path"] == "f32_group"
+
+
+def test_rule_tiles_pad_like_128_tiles():
+    """A dim over 128 that is not a multiple of it pads to the next 128,
+    as with 128 tiles — the rule's whole-dim edge is never clipped to a
+    ragged one — and gives the 128 tiles' bits."""
+    assert autotune.lookup("matmul_fwd", 64, 200, 500) == (64, 256, 512)
+    x = jax.random.normal(jax.random.key(0), (64, 200))
+    w = jax.random.normal(jax.random.key(1), (200, 500)) * 0.1
+    np.testing.assert_array_equal(
+        np.asarray(ops.hbfp_matmul(x, w, mantissa_bits=8)),
+        np.asarray(ops.hbfp_matmul(x, w, mantissa_bits=8, bm=64, bk=128,
+                                   bn=128)))
+
+
+def test_gemm_events_reach_the_recorder_at_trace():
+    """A make_step recorder with sinks gets one `kernel/gemm` event per
+    GEMM of every kernel call site when the step traces (fwd and dgrad on
+    the int8 path under a uniform policy); outside `gemm_events` nothing
+    is sent."""
+    from repro.models import init_params
+    from repro.optim import make_schedule
+    from repro.train import init_train_state, make_step
+    arch = _tiny_arch(kernel_backend="pallas")
+    sink = MemorySink()
+    step = make_step(arch, "8; backend=pallas",
+                     make_schedule("constant", base_lr=1e-3, warmup_steps=0,
+                                   total_steps=10),
+                     recorder=Recorder([sink]))
+    state = jax.eval_shape(
+        lambda k: init_train_state(k, arch, init_params), jax.random.key(0))
+    jax.eval_shape(step, state, _batch(), jax.random.key(3))
+    evs = [e.data for e in sink.events if e.kind == "kernel/gemm"]
+    paths = {}
+    for e in evs:
+        paths.setdefault(e["op"], set()).add(e["path"])
+    assert paths == {"matmul_fwd": {"int8_group"},
+                     "matmul_dgrad": {"int8_group"},
+                     "matmul_wgrad": {"f32_wgrad"}}
+    assert len(evs) == 3 * 8     # q, k, v, o, three FFN, head
+    n = len(sink.events)
+    resolve_spec(HBFPConfig(8, 16), 96, 64, 32)
+    assert len(sink.events) == n

@@ -7,7 +7,8 @@ import pytest
 
 from repro.kernels import ops, ref
 from repro.kernels.bfp_quantize import bfp_quantize_pallas
-from repro.kernels.hbfp_matmul import hbfp_matmul_pallas
+from repro.kernels.hbfp_matmul import (hbfp_dgrad_pallas, hbfp_matmul_pallas,
+                                       hbfp_wgrad_pallas)
 
 SHAPES_Q = [(64, 64), (128, 256), (192, 64), (256, 384), (100, 200),
             (130, 72)]
@@ -174,6 +175,82 @@ def test_int8_path_exactness():
     wq = bfp.quantize(w, 8, (None, None))
     np.testing.assert_allclose(np.asarray(y8), np.asarray(xq @ wq),
                                rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# large tiles: the exponent group stays 128 whatever the tile (DESIGN.md §13)
+# ---------------------------------------------------------------------------
+
+BIG = (256, 512, 384)               # (M, K, N), tiles (256, 512, 384)
+GEMM_KERNELS = {"fwd": (hbfp_matmul_pallas, ref.hbfp_matmul_ref),
+                "dgrad": (hbfp_dgrad_pallas, ref.hbfp_dgrad_ref),
+                "wgrad": (hbfp_wgrad_pallas, ref.hbfp_wgrad_ref)}
+
+
+def _grouped(key, rows, cols):
+    """Normal values whose scale jumps per 128 x 128 block, so one
+    exponent per tile row or tile would round them differently."""
+    k1, k2 = jax.random.split(jax.random.key(key))
+    scale = jnp.exp2(jnp.round(3 * jax.random.normal(
+        k2, (rows // 128, cols // 128))))
+    scale = jnp.repeat(jnp.repeat(scale, 128, 0), 128, 1)
+    return jax.random.normal(k1, (rows, cols)) * scale
+
+
+def _operands(op):
+    M, K, N = BIG
+    a_shape, b_shape = {"fwd": ((M, K), (K, N)), "dgrad": ((M, N), (K, N)),
+                        "wgrad": ((M, K), (M, N))}[op]
+    return _grouped(1, *a_shape), _grouped(2, *b_shape) * 0.1
+
+
+@pytest.mark.parametrize("op", ["fwd", "dgrad"])
+@pytest.mark.parametrize("stochastic", [False, True])
+def test_large_tiles_bit_identical_to_128(op, stochastic):
+    """fwd and dgrad at one large tile contract the same 128-groups in the
+    same order as 128³ tiles: identical bits, and the oracle's at the
+    large tile; a 512-wide exponent group (block=512) does differ."""
+    kernel, oracle = GEMM_KERNELS[op]
+    a, b = _operands(op)
+    seed = jnp.full((1, 1), 7, jnp.int32) if stochastic else None
+    kw = dict(mantissa_bits=8, stochastic=stochastic)
+    big = kernel(a, b, seed, bm=256, bk=512, bn=384, interpret=True, **kw)
+    small = kernel(a, b, seed, bm=128, bk=128, bn=128, interpret=True, **kw)
+    np.testing.assert_array_equal(np.asarray(big), np.asarray(small))
+    r = oracle(a, b, 7 if stochastic else None, bm=256, bk=512, bn=384, **kw)
+    np.testing.assert_array_equal(np.asarray(big), np.asarray(r))
+    coarse = kernel(a, b, seed, bm=256, bk=512, bn=384, block=512,
+                    interpret=True, **kw)
+    assert not np.array_equal(np.asarray(big), np.asarray(coarse))
+
+
+@pytest.mark.parametrize("stochastic", [False, True])
+def test_large_tiles_wgrad_agrees_to_f32_rounding(stochastic):
+    """wgrad keeps its exponent groups at large tiles; only the f32 sum
+    over a longer token tile may round differently."""
+    kernel, oracle = GEMM_KERNELS["wgrad"]
+    x, g = _operands("wgrad")
+    seed = jnp.full((1, 1), 7, jnp.int32) if stochastic else None
+    kw = dict(mantissa_bits=8, stochastic=stochastic)
+    big = np.asarray(kernel(x, g, seed, bm=256, bk=512, bn=384,
+                            interpret=True, **kw))
+    small = np.asarray(kernel(x, g, seed, bm=128, bk=128, bn=128,
+                              interpret=True, **kw))
+    np.testing.assert_allclose(big, small, rtol=1e-5,
+                               atol=1e-6 * np.abs(small).max())
+    r = oracle(x, g, 7 if stochastic else None, bm=256, bk=512, bn=384, **kw)
+    np.testing.assert_array_equal(big, np.asarray(r))
+
+
+@pytest.mark.parametrize("op", ["fwd", "dgrad", "wgrad"])
+def test_block16_large_tiles_match_oracle(op):
+    """Sub-128 groups keep the dequantize-in-VMEM path at any tile."""
+    kernel, oracle = GEMM_KERNELS[op]
+    a, b = _operands(op)
+    kw = dict(mantissa_bits=8, block=16, bm=256, bk=256, bn=128)
+    np.testing.assert_array_equal(
+        np.asarray(kernel(a, b, None, interpret=True, **kw)),
+        np.asarray(oracle(a, b, None, **kw)))
 
 
 @pytest.mark.slow

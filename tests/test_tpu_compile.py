@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.kernels import autotune
 from repro.kernels.bfp_quantize import bfp_quantize_pallas
 from repro.kernels.hbfp_flash_attn import FlashSpec, flash_attention_vjp
 from repro.kernels.hbfp_matmul import (hbfp_dgrad_pallas, hbfp_matmul_pallas,
@@ -57,30 +58,74 @@ def _assert_kernel(hlo: str, name: str):
 
 SEED = ((1, 1), jnp.int32)
 
+# weight GEMMs (M, K, N) of the benchmark's configurations at B·S = 4096
+# tokens (the head on loss chunks of 2048), compiled at the tiles the shape
+# rule picks for them
+RULE_GEMMS = {
+    "yi.ffn_up": (4096, 4096, 11008), "yi.ffn_down": (4096, 11008, 4096),
+    "yi.attn_qo": (4096, 4096, 4096), "yi.attn_kv": (4096, 4096, 512),
+    "yi.head": (2048, 4096, 8000),
+    "minicpm.attn": (4096, 2304, 2304), "minicpm.ffn_up": (4096, 2304, 5760),
+    "minicpm.ffn_down": (4096, 5760, 2304),
+    "minicpm.head": (2048, 2304, 15344),
+}
 
-@pytest.mark.parametrize("quantize_w", [True, False])
-@pytest.mark.parametrize("stochastic", [False, True])
-def test_matmul_fwd_compiles(one_chip, quantize_w, stochastic):
+
+def _gemm(op, gemm):
+    """(M, K, N) padded to 128 and the tiles to compile at: the kernels'
+    default 128 tiles at yi-9b's FFN width (gemm None), else the shape
+    rule's for one benchmark GEMM."""
+    if gemm is None:
+        return (M, K, N), {}
+    dims = RULE_GEMMS[gemm]
+    bm, bk, bn = autotune.shape_tiles(f"matmul_{op}", *dims)
+    return tuple(-(-d // 128) * 128 for d in dims), dict(bm=bm, bk=bk, bn=bn)
+
+
+def _cases(old_ids):
+    """The 128-tile cases under their former ids, then every benchmark
+    GEMM at its rule tiles (nearest), and yi's FFN-up rule tiles with
+    stochastic rounding and with pre-narrowed weights."""
+    out = [pytest.param(None, *args, id=i) for i, args in old_ids]
+    out += [pytest.param(g, True, False, id=g) for g in RULE_GEMMS]
+    out += [pytest.param("yi.ffn_up", True, True, id="yi.ffn_up-stochastic"),
+            pytest.param("yi.ffn_up", False, False, id="yi.ffn_up-narrow_w")]
+    return out
+
+
+@pytest.mark.parametrize("gemm,quantize_w,stochastic", _cases(
+    [("False-True", (True, False)), ("False-False", (False, False)),
+     ("True-True", (True, True)), ("True-False", (False, True))]))
+def test_matmul_fwd_compiles(one_chip, gemm, quantize_w, stochastic):
+    (m, k, n), tiles = _gemm("fwd", gemm)
     fn = functools.partial(hbfp_matmul_pallas, mantissa_bits=8,
-                           stochastic=stochastic, quantize_w=quantize_w)
-    hlo = _compile(fn, one_chip, ((M, K), jnp.bfloat16),
-                   ((K, N), jnp.bfloat16), SEED)
+                           stochastic=stochastic, quantize_w=quantize_w,
+                           **tiles)
+    hlo = _compile(fn, one_chip, ((m, k), jnp.bfloat16),
+                   ((k, n), jnp.bfloat16), SEED)
     _assert_kernel(hlo, "hbfp_matmul_fwd")
 
 
-@pytest.mark.parametrize("quantize_w", [True, False])
-def test_matmul_dgrad_compiles(one_chip, quantize_w):
+@pytest.mark.parametrize("gemm,quantize_w,stochastic", _cases(
+    [("True", (True, False)), ("False", (False, False))]))
+def test_matmul_dgrad_compiles(one_chip, gemm, quantize_w, stochastic):
+    (m, k, n), tiles = _gemm("dgrad", gemm)
     fn = functools.partial(hbfp_dgrad_pallas, mantissa_bits=8,
-                           quantize_w=quantize_w)
-    hlo = _compile(fn, one_chip, ((M, N), jnp.float32),
-                   ((K, N), jnp.bfloat16), SEED)
+                           stochastic=stochastic, quantize_w=quantize_w,
+                           **tiles)
+    hlo = _compile(fn, one_chip, ((m, n), jnp.float32),
+                   ((k, n), jnp.bfloat16), SEED)
     _assert_kernel(hlo, "hbfp_matmul_dgrad")
 
 
-def test_matmul_wgrad_compiles(one_chip):
-    fn = functools.partial(hbfp_wgrad_pallas, mantissa_bits=8)
-    hlo = _compile(fn, one_chip, ((M, K), jnp.bfloat16),
-                   ((M, N), jnp.float32), SEED)
+@pytest.mark.parametrize("gemm,quantize_w,stochastic", _cases(
+    [("yi.ffn_128", (True, False))])[:-1])
+def test_matmul_wgrad_compiles(one_chip, gemm, quantize_w, stochastic):
+    (m, k, n), tiles = _gemm("wgrad", gemm)
+    fn = functools.partial(hbfp_wgrad_pallas, mantissa_bits=8,
+                           stochastic=stochastic, **tiles)
+    hlo = _compile(fn, one_chip, ((m, k), jnp.bfloat16),
+                   ((m, n), jnp.float32), SEED)
     _assert_kernel(hlo, "hbfp_matmul_wgrad")
 
 
