@@ -5,9 +5,15 @@ A cell is one entry of BENCHMARK.json's `workloads`. Everything that
 belongs to one configuration, traffic mix or per-layer metric sits in a
 file of its own under bench/, found by the name BENCHMARK.json gives:
 
-  bench/configs/<config>.json   sizes as run, published values, cut
-  bench/traffic/<traffic>.json  the mix's parameters (`kind` names the
-                                runner; training is the one there is)
+  bench/configs/<config>.json   sizes as run, published values, cut;
+                                `structure` names the model's module
+  bench/structures/<structure>.py  the model: the program's arch, seeded
+                                weights, f32 reference, work counts
+                                (`dense_decoder` where the file names
+                                none; the contract is in its __init__)
+  bench/traffic/<traffic>.json  the mix's parameters; `kind` names the
+                                runner
+  bench/<kind>_cell.py          the runner: run(), finish(), calibrate()
   bench/limits/<workload>.json  limits of the correctness comparison
   bench/metrics/<metric>.py     a reader: read(reading) -> value or None
 """
@@ -126,6 +132,15 @@ class CompileCounter:
             self.hits += 1
         elif event == "/jax/compilation_cache/cache_misses":
             self.misses += 1
+
+
+def load_runner(kind: str):
+    """The runner of a traffic kind, bench/<kind>_cell.py."""
+    if not (isinstance(kind, str) and kind.isidentifier() and os.path.isfile(
+            os.path.join(BENCH, kind + "_cell.py"))):
+        raise SetupError(f"no runner bench/{kind}_cell.py for traffic of "
+                         f"kind {kind!r}")
+    return importlib.import_module(kind + "_cell")
 
 
 def load_reader(metric: str):

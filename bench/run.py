@@ -27,6 +27,7 @@ import sys  # noqa: E402
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import harness  # noqa: E402
+import structures  # noqa: E402
 from harness import log  # noqa: E402
 
 
@@ -82,9 +83,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     try:
         cell = harness.find_cell(args.workload)
-        if cell.traffic["kind"] != "train":
-            raise harness.SetupError(
-                f"no runner for traffic of kind {cell.traffic['kind']!r}")
+        structures.of(cell.config)
+        runner = harness.load_runner(cell.traffic["kind"])
         device = harness.device_info(cell.workload["chips"])
         peak = harness.peak_of(cell.peaks, device["kind"])
     except (harness.SetupError, OSError, KeyError) as e:
@@ -96,7 +96,6 @@ def main(argv=None) -> int:
     counter = harness.CompileCounter()
     cfg = cell.config
     log(f"config {cell.workload['config']}: {cfg['cut']}")
-    import train_cell as runner
     tracer = None
     trace_dir = os.path.join(harness.ROOT, ".bench_trace",
                              args.workload)

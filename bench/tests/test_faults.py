@@ -7,9 +7,13 @@ returns its state unchanged; half of each batch left out, the mean taken
 over the rest. (The cells run on one chip, so no exchange between chips
 can be left out, and they produce no token or answer to alter.)
 """
-import calibrate
+import pytest
+
 import tiny
 import train_cell
+
+MIXES = {"hbfp8": (tiny.TRAIN, tiny.LIMITS),
+         "bf16": (tiny.TRAIN_BF16, tiny.LIMITS_BF16)}
 
 
 def correct(runner, cell, seed=2**35 + 1, seconds=1.0):
@@ -17,11 +21,18 @@ def correct(runner, cell, seed=2**35 + 1, seconds=1.0):
     return all(c["ok"] for c in runner.finish(cell, seed, out))
 
 
-def test_sound_training_run_is_correct():
-    assert correct(train_cell, tiny.cell(tiny.TRAIN))
+def mix_cell(mix):
+    traffic, limits = MIXES[mix]
+    return tiny.cell(traffic, limits=limits)
 
 
-def test_step_returning_its_state_unchanged_is_caught(monkeypatch):
+@pytest.mark.parametrize("mix", MIXES)
+def test_sound_training_run_is_correct(mix):
+    assert correct(train_cell, mix_cell(mix))
+
+
+@pytest.mark.parametrize("mix", MIXES)
+def test_step_returning_its_state_unchanged_is_caught(monkeypatch, mix):
     real = train_cell.make_step
 
     def frozen(arch, policy, sched, donate=False, **kw):
@@ -32,12 +43,13 @@ def test_step_returning_its_state_unchanged_is_caught(monkeypatch):
         return run
 
     monkeypatch.setattr(train_cell, "make_step", frozen)
-    assert not correct(train_cell, tiny.cell(tiny.TRAIN))
+    assert not correct(train_cell, mix_cell(mix))
 
 
-def test_half_the_batch_left_out_is_caught(monkeypatch):
+@pytest.mark.parametrize("mix", MIXES)
+def test_half_the_batch_left_out_is_caught(monkeypatch, mix):
     real = train_cell.train_batch
     monkeypatch.setattr(train_cell, "train_batch",
-                        lambda *a: calibrate.halve(real(*a)))
-    assert not correct(train_cell, tiny.cell(tiny.TRAIN))
+                        lambda *a: train_cell.halve(real(*a)))
+    assert not correct(train_cell, mix_cell(mix))
 

@@ -7,6 +7,7 @@ import re
 import pytest
 
 import harness
+import structures
 
 SPEC = harness.load_json(os.path.join(harness.ROOT, "BENCHMARK.json"))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -53,14 +54,21 @@ def test_names_units_and_lines():
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_files_are_found_by_name(cell):
     c = harness.find_cell(cell)
-    assert c.traffic["kind"] == "train"
+    runner = harness.load_runner(c.traffic["kind"])
+    assert all(callable(getattr(runner, f))
+               for f in ("run", "finish", "calibrate"))
+    assert structures.of(c.config).__name__.startswith("structures.")
     assert any(m["name"] == "setup_s" for m in c.end_to_end)
     assert len(c.end_to_end) >= 2 and c.per_layer
     for m in c.end_to_end + c.per_layer:
         assert callable(harness.load_reader(m["name"]))
-    for k, v in c.limits.items():
-        if k != "control":
-            assert v["limit"] >= 0
+    numbers = set(c.limits) - {"control"}
+    assert numbers and numbers <= {"loss_gap", "grad_norm_gap",
+                                   "change_norm_gap"}
+    for k in numbers:
+        assert c.limits[k]["limit"] >= 0
+    assert set(c.limits["control"]) in ({"policy", "why"},
+                                        {"rounding", "why"})
     assert "TPU v5 lite" in c.peaks["devices"]
 
 

@@ -1,5 +1,5 @@
 """The benchmark's f32 reference against the program's fp32 forward, at a
-small size on the CPU."""
+small size on the CPU, and the fp8 rounding a control puts in it."""
 import dataclasses
 
 import jax
@@ -7,7 +7,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import harness
 import reference
+import structures
 import tiny
 from program import program_arch
 from weights import make_params, seed_key, train_batch
@@ -46,3 +48,31 @@ def test_reference_adamw_moves_every_leaf():
     # the first clipped gradient has global norm at most the clip
     total = sum(float(x) ** 2 for x in jax.tree.leaves(r["grad1"])) ** 0.5
     assert total <= 1.0 + 1e-5
+
+
+def test_fp8_rounding_applies_inside_its_context_only():
+    a = jax.random.normal(jax.random.key(0), (64, 128), jnp.float32)
+    b = jax.random.normal(jax.random.key(1), (128, 32), jnp.float32)
+    exact = np.asarray(a, np.float64) @ np.asarray(b, np.float64)
+
+    def loss(a, b):
+        return jnp.sum(structures.mm("mk,kn->mn", a, b) ** 2)
+
+    with structures.rounded("fp8"):
+        out = structures.mm("mk,kn->mn", a, b)
+        val8, g8 = jax.value_and_grad(loss, argnums=(0, 1))(a, b)
+    val, g = jax.value_and_grad(loss, argnums=(0, 1))(a, b)
+    err = np.abs(np.asarray(out) - exact).max() / np.abs(exact).max()
+    assert 1e-3 < err < 0.1        # e4m3 keeps three mantissa bits
+    assert abs(float(val8) / float(val) - 1) < 0.1
+    for x8, x in zip(g8, g):
+        rel = float(jnp.linalg.norm(x8 - x) / jnp.linalg.norm(x))
+        assert 1e-3 < rel < 0.2
+    np.testing.assert_allclose(structures.mm("mk,kn->mn", a, b), exact,
+                               rtol=1e-5, atol=1e-4)
+
+
+def test_unknown_rounding_is_a_setup_error():
+    with pytest.raises(harness.SetupError):
+        with structures.rounded("int3"):
+            pass

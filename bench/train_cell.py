@@ -6,10 +6,13 @@ and feed, and reads what the comparison needs: each step's loss, the
 first gradient as the optimizer got it (its first moment over 1 - b1),
 and the parameters' change over those steps. The window then goes on from
 the same Trainer. After the window the program's state is freed and the
-plain reference runs the same steps.
+plain reference runs the same steps. `calibrate` gives the readings the
+cell's limits are set from (bench/calibrate.py).
 """
 from __future__ import annotations
 
+import collections
+import dataclasses
 import gc
 import math
 from time import perf_counter
@@ -30,11 +33,14 @@ from repro.train.trainer import Trainer
 from weights import make_params, seed_key, train_batch
 
 CHECKED = 3
+# seconds of steps dispatched ahead of the one the window waits for
+AHEAD_S = 6.0
 
 
-def build(cell, seed: int):
+def build(cell, seed: int, rows=None):
     """The program's Trainer at the cell's sizes, from the seed, and the
-    list its step appends each loss to."""
+    list its step appends each loss to. `rows` makes a step's batch
+    (train_batch unless given)."""
     cfg, tr = cell.config, cell.traffic
     arch = program_arch(cfg)
     hp = tr["optimizer"]
@@ -55,7 +61,7 @@ def build(cell, seed: int):
     state = jax.jit(lambda k: init_train_state(
         k, arch, lambda kk, _: make_params(cfg, kk)))(wkey)
     B, S, V = tr["batch"], tr["seq"], cfg["vocab_size"]
-    make = jax.jit(train_batch, static_argnums=(2, 3, 4))
+    make = jax.jit(rows or train_batch, static_argnums=(2, 3, 4))
     trainer = Trainer(train_step=step, init_state=state,
                       data_fn=lambda s: make(dkey, s, B, S, V),
                       ckpt_dir=None, hbfp=pol, seed=seed & 0x7FFFFFFF)
@@ -89,35 +95,50 @@ def checked_steps(trainer, losses, b1: float) -> dict:
 
 
 def window(trainer, losses, first: int, done):
-    """Steps from `first`, one in flight, until done(steps, seconds) says
-    so; every dispatched step finishes inside the window. Returns (steps,
-    window seconds)."""
+    """Steps from `first` until done(steps, seconds) says so. About
+    AHEAD_S seconds of steps, by the rate of those finished so far, stay
+    dispatched ahead of the one waited for, so the chip stays fed while
+    the host stands still. Then nothing more is sent, and every sent step
+    finishes inside the window. Returns (steps, window seconds)."""
     t0 = perf_counter()
     n = first
-    prev = None
+    sent = collections.deque()
+    finished = 0
+    longest, at, last = 0.0, 0.0, t0
     while True:
         with TraceAnnotation("bench.step_dispatch"):
             run_steps(trainer, n, 1)
         n += 1
-        if prev is not None:
+        sent.append(losses[-1])
+        ahead = max(1, int(AHEAD_S * finished / (perf_counter() - t0)))
+        while len(sent) > ahead:
             with TraceAnnotation("bench.sync"):
-                jax.block_until_ready(prev)
-        prev = losses[-1]
-        if done(n - first, perf_counter() - t0):
+                jax.block_until_ready(sent.popleft())
+            finished += 1
+        now = perf_counter()
+        if now - last > longest:
+            longest, at = now - last, last - t0
+        last = now
+        if done(n - first, now - t0):
             break
     with TraceAnnotation("bench.sync"):
         jax.block_until_ready(trainer.state)
-    return n - first, perf_counter() - t0
+    win = perf_counter() - t0
+    log(f"window: {n - first} steps in {win:.3f} s, {ahead} ahead at the "
+        f"end; longest pass of the host's loop {longest:.3f} s, from "
+        f"{at:.3f} s")
+    return n - first, win
 
 
-def reference_readings(cell, seed: int) -> dict:
+def reference_readings(cell, seed: int, rounding=None) -> dict:
     cfg, tr = cell.config, cell.traffic
     wkey, dkey = jax.random.split(seed_key(seed))
     params0 = jax.device_get(jax.jit(lambda k: make_params(cfg, k))(wkey))
     batches = [weights.train_batch(dkey, s, tr["batch"], tr["seq"],
                                    cfg["vocab_size"])
                for s in range(CHECKED)]
-    r = reference.train_readings(cfg, tr["optimizer"], params0, batches)
+    r = reference.train_readings(cfg, tr["optimizer"], params0, batches,
+                                 rounding)
     return {"losses": r["losses"], "grad1": compare.flat(r["grad1"]),
             "grad1_raw": compare.flat(r["grad1_raw"]),
             "change": compare.flat(r["change"])}
@@ -158,3 +179,60 @@ def finish(cell, seed: int, out: dict) -> list:
     ref = reference_readings(cell, seed)
     log(f"reference losses {ref['losses']}")
     return compare.train_checks(out["prog"], ref, cell.limits)
+
+
+def halve(batch):
+    """Half of the batch left out: the first half of the rows, or of the
+    positions when there is one row."""
+    t, l = batch["tokens"], batch["labels"]
+    if t.shape[0] >= 2:
+        n = t.shape[0] // 2
+        return {"tokens": t[:n], "labels": l[:n]}
+    n = t.shape[1] // 2
+    return {"tokens": t[:, :n], "labels": l[:, :n]}
+
+
+def calibration_program(cell, seed: int, policy=None, fault=False) -> dict:
+    """The program's side of the check, under `policy` in place of the
+    mix's, or with half of each batch left out (`fault`)."""
+    if policy is not None:
+        cell = dataclasses.replace(
+            cell, traffic=dict(cell.traffic, policy=policy))
+    rows = (lambda *a: halve(train_batch(*a))) if fault else None
+    trainer, losses = build(cell, seed, rows)
+    prog = checked_steps(trainer, losses, cell.traffic["optimizer"]["b1"])
+    del trainer, losses
+    gc.collect()
+    return prog
+
+
+def control_readings(cell, seed: int, control: dict) -> dict:
+    """The control's side of the check: the program under the control's
+    `policy`, or the plain reference with its contractions' operands
+    rounded to `rounding` put in the program's place."""
+    if "rounding" in control:
+        return reference_readings(cell, seed, control["rounding"])
+    return calibration_program(cell, seed, control["policy"])
+
+
+def calibrate(cell, seeds, control_seeds, fault_seeds, control=None):
+    """Yield one row of readings (kind, seed, the numbers compared) for
+    the program on each seed, for the control (by default the one the
+    limits file names) on each control seed, and for half of each batch
+    left out on each fault seed."""
+    control = control or cell.limits["control"]
+    for seed in dict.fromkeys(list(seeds) + list(control_seeds)
+                              + list(fault_seeds)):
+        ref = reference_readings(cell, seed)
+        gc.collect()
+        runs = []
+        if seed in seeds:
+            runs.append(("program", calibration_program(cell, seed)))
+        if seed in control_seeds:
+            runs.append(("control", control_readings(cell, seed, control)))
+        if seed in fault_seeds:
+            runs.append(("half_batch",
+                         calibration_program(cell, seed, fault=True)))
+        for kind, prog in runs:
+            yield {"kind": kind, "seed": seed,
+                   **compare.train_readings(prog, ref)}

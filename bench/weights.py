@@ -4,12 +4,15 @@ Both the system under test and the plain reference read these: the
 weights are the benchmark's, not the program's, so the reference takes
 nothing that the program has made. The tree uses the program's parameter
 names, stacked over layers ([L, ...] leaves), because that is the
-interface through which the program takes weights.
+interface through which the program takes weights; the structure module
+(bench/structures/) says which leaves a model has.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+import structures
 
 
 def seed_key(seed: int):
@@ -21,34 +24,9 @@ def seed_key(seed: int):
 
 
 def make_params(cfg: dict, key, dtype=jnp.bfloat16):
-    """Random weights of a dense pre-norm decoder at the configuration's
-    sizes: normal, scaled by fan-in (0.02 for the embedding), norm scales
-    at one. Call under jit: one device program makes the whole tree."""
-    D, F = cfg["hidden_size"], cfg["intermediate_size"]
-    H, Hkv, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                  cfg["head_dim"])
-    L, V = cfg["num_hidden_layers"], cfg["vocab_size"]
-    ks = jax.random.split(key, 9)
-
-    def normal(k, shape, fan_in):
-        return (jax.random.normal(k, shape, jnp.float32)
-                * fan_in ** -0.5).astype(dtype)
-
-    layers = {
-        "ln1_norm_scale": jnp.ones((L, D), jnp.float32),
-        "ln2_norm_scale": jnp.ones((L, D), jnp.float32),
-        "attn_wq": normal(ks[0], (L, D, H * hd), D),
-        "attn_wk": normal(ks[1], (L, D, Hkv * hd), D),
-        "attn_wv": normal(ks[2], (L, D, Hkv * hd), D),
-        "attn_wo": normal(ks[3], (L, H * hd, D), H * hd),
-        "ffn_wg": normal(ks[4], (L, D, F), D),
-        "ffn_wi": normal(ks[5], (L, D, F), D),
-        "ffn_wo": normal(ks[6], (L, F, D), F),
-    }
-    return {"layers": layers,
-            "final_norm_scale": jnp.ones((D,), jnp.float32),
-            "embed_table": normal(ks[7], (V, D), 2500.0),  # std 0.02
-            "head_w": normal(ks[8], (D, V), D)}
+    """Random weights of the configuration's model at its sizes. Call
+    under jit: one device program makes the whole tree."""
+    return structures.of(cfg).make_params(cfg, key, dtype)
 
 
 def train_batch(key, step, batch: int, seq: int, vocab: int):

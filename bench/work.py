@@ -1,7 +1,8 @@
 """Work a cell needs, counted from the model's shapes alone.
 
 Operations and bytes come from the configuration's GEMM and attention
-shapes at the cell's declared arithmetic, never from tiles, kernel
+shapes (its structure module's, bench/structures/) at the cell's
+declared arithmetic, never from tiles, kernel
 internals or compiled HLO, so that every implementation of the same work
 is read against the same count. Recomputation (remat) is not counted.
 
@@ -18,6 +19,8 @@ Conventions:
 """
 from __future__ import annotations
 
+import structures
+
 OPERAND_BYTES = {"int8": 1, "bf16": 2}
 RESULT_BYTES = 2
 STAT_BYTES = 4
@@ -25,18 +28,8 @@ STAT_BYTES = 4
 
 def weight_gemms(cfg: dict, tokens: int):
     """(name, M, K, N) of every weight GEMM of one forward pass over
-    `tokens` tokens: each layer's projections, then the LM head."""
-    D, F = cfg["hidden_size"], cfg["intermediate_size"]
-    H, Hkv, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                  cfg["head_dim"])
-    per_layer = [("wq", D, H * hd), ("wk", D, Hkv * hd),
-                 ("wv", D, Hkv * hd), ("wo", H * hd, D),
-                 ("ffn_g", D, F), ("ffn_i", D, F), ("ffn_o", F, D)]
-    out = []
-    for layer in range(cfg["num_hidden_layers"]):
-        out += [(f"{layer}.{n}", tokens, k, n_) for n, k, n_ in per_layer]
-    out.append(("head", tokens, D, cfg["vocab_size"]))
-    return out
+    `tokens` tokens, as the configuration's structure has them."""
+    return structures.of(cfg).weight_gemms(cfg, tokens)
 
 
 def matmul_params(cfg: dict) -> int:
@@ -45,16 +38,18 @@ def matmul_params(cfg: dict) -> int:
 
 
 def attention_fwd_ops(cfg: dict, batch: int, seq: int) -> int:
-    """Causal attention operations of one forward pass, all layers."""
-    H, hd = cfg["num_attention_heads"], cfg["head_dim"]
-    return (cfg["num_hidden_layers"] * batch * H
-            * 2 * 2 * hd * seq * (seq + 1) // 2)
+    """Attention operations of one forward pass, all layers, as the
+    configuration's structure has them."""
+    return structures.of(cfg).attention_fwd_ops(cfg, batch, seq)
 
 
 def train_step_ops(cfg: dict, batch: int, seq: int) -> int:
     """Model operations of one training step (forward and backward):
-    6 per GEMM parameter and token, plus causal attention."""
-    return (6 * matmul_params(cfg) * batch * seq
+    three passes of every weight GEMM at the rows it takes (6 per GEMM
+    parameter and token where each takes every token), plus causal
+    attention."""
+    return (sum(6 * M * K * N for _, M, K, N in
+                weight_gemms(cfg, batch * seq))
             + 3 * attention_fwd_ops(cfg, batch, seq))
 
 
@@ -84,8 +79,7 @@ def attention_least_seconds(cfg: dict, batch: int, seq: int, arith: str,
     """Least time of causal attention in one training step: the forward
     (reads Q, K, V; writes O and the row statistics) and the backward
     (reads Q, K, V, O, dO and the statistics; writes dQ, dK, dV)."""
-    L, H, Hkv, hd = (cfg["num_hidden_layers"], cfg["num_attention_heads"],
-                     cfg["num_key_value_heads"], cfg["head_dim"])
+    L, H, Hkv, hd = structures.of(cfg).attention_shape(cfg)
     ob = OPERAND_BYTES[arith]
     q = batch * H * seq * hd          # elements of Q, O, dO, dQ
     kv = batch * Hkv * seq * hd       # elements of K, V, dK, dV
